@@ -1,8 +1,9 @@
 """Architecture + run configuration.
 
 One ``ArchConfig`` instance per assigned architecture (see sibling modules).
-``reduced()`` returns a same-family miniature for CPU smoke tests; the full
-configs are only ever lowered abstractly (ShapeDtypeStruct) by the dry-run.
+``reduced()`` returns a same-family miniature for CPU smoke tests.  The full
+configs are lowered abstractly (ShapeDtypeStruct) by the dry-run, and the
+ones that fit one TPU chip whole (phi4-mini-3.8b) are served at full width.
 """
 from __future__ import annotations
 

@@ -44,7 +44,11 @@ def _producer_main(store_config_blob: bytes, make_batch_blob: bytes,
 
 
 class ProxyDataPipeline:
-    """Iterator of resolved batches with prefetch-by-proxy."""
+    """Iterator of resolved batches with prefetch-by-proxy.
+
+    ``make_batch`` runs in spawned producer processes, so it builds host
+    (NumPy) arrays and never touches JAX: on a TPU host the chip belongs
+    to the consuming process."""
 
     def __init__(self, store: Store, make_batch: Callable[[int], Any], *,
                  n_producers: int = 2, redundancy: int = 1,

@@ -53,6 +53,7 @@ from repro.core.store import StoreConfig, get_or_create_store
 from repro.data.datasets import lm_batch
 from repro.distributed.compression import Compressor
 from repro.federated.faas import FaasExecutor, PayloadTooLarge
+from repro.runtime import refuse_child_workers_on_tpu
 
 
 @dataclass
@@ -143,6 +144,8 @@ class FLOrchestrator:
     def __init__(self, cfg: ArchConfig, fl: FLConfig,
                  executor: FaasExecutor, store: Store | None,
                  monitor_group: str | None = None) -> None:
+        # the executor's workers jit and run local training
+        refuse_child_workers_on_tpu("a federated round's FaaS workers")
         self.cfg, self.fl = cfg, fl
         self.executor = executor
         self.store = store

@@ -4,7 +4,8 @@ Each kernel ships as <name>.py (pl.pallas_call + explicit BlockSpec VMEM
 tiling), with ops.py as the jit'd differentiable wrapper and ref.py as the
 pure-jnp oracle used by tests/test_kernels.py allclose sweeps.
 
-CPU container: interpret=True (validation); TPU: REPRO_PALLAS_COMPILED=1.
+Each kernel compiles on a TPU backend and runs interpreted on any other
+(``repro.runtime.kernel_interpret``); ``interpret=`` overrides that.
 """
 from repro.kernels.ops import decode_attention, flash_attention, ssd_scan
 

@@ -1,15 +1,18 @@
 """Flash attention (causal, GQA, optional sliding window) — Pallas TPU.
 
-TPU-native adaptation (DESIGN.md §2): tiles are MXU-aligned (q/k blocks are
-multiples of 128 where shapes allow), the online-softmax running state
-(m, l, acc) lives in VMEM scratch and persists across the innermost
-(arbitrary-semantics) K-block grid dimension, and the K/V stream stays in
-(block_k, HD) tiles so the working set is ~4 * block * HD * dtype bytes —
-far under v5e VMEM at the default 128x128 tiling.
+Tiles are MXU-aligned (q/k blocks are multiples of 128 where shapes
+allow), the online-softmax running state (m, l, acc) lives in VMEM scratch
+and persists across the innermost (arbitrary-semantics) K-block grid
+dimension, and the K/V stream stays in (block_k, HD) tiles so the working
+set is ~4 * block * HD * dtype bytes — far under v5e VMEM at the default
+128x128 tiling.
+
+The wrapper puts the head axis before the sequence axis, so every block's
+last two dims are (block, HD) as the TPU lowering requires; the transposes
+cost O(S * H * HD), against the kernel's O(S^2 * H * HD).
 
 Grid: (B, H, nQ, nK), nK innermost/sequential.  Causal skipping is done by
-masking; a production variant would prune fully-masked K blocks with a
-scalar-prefetch grid map (noted in EXPERIMENTS.md §Perf).
+masking; fully-masked K blocks are not pruned.
 """
 from __future__ import annotations
 
@@ -21,9 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells it TPUCompilerParams; >= 0.5 renamed it CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.runtime import kernel_interpret
 
 NEG_INF = -1e30
 
@@ -39,9 +40,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    q = q_ref[0, :, 0, :]                      # (block_q, HD)
-    k = k_ref[0, :, 0, :]                      # (block_k, HD)
-    v = v_ref[0, :, 0, :]                      # (block_k, HD)
+    q = q_ref[...]                              # (block_q, HD)
+    k = k_ref[...]                              # (block_k, HD)
+    v = v_ref[...]                              # (block_k, HD)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -57,30 +58,32 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
         mask &= (q_pos - k_pos) < window
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_sc[...]                          # (block_q,)
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    m_prev = m_sc[...]                          # (block_q, 1)
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
+    p = jnp.exp(s - m_cur)
     p = jnp.where(mask, p, 0.0)
 
-    l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1)
-    acc_sc[...] = acc_sc[...] * alpha[:, None] + jax.lax.dot_general(
+    l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_sc[...] = m_cur
 
     @pl.when(ik == n_k - 1)
     def _finish():
-        denom = jnp.maximum(l_sc[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = (acc_sc[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_sc[...], 1e-30)
+        o_ref[...] = (acc_sc[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(
     jax.jit, static_argnames=("causal", "window", "q_offset", "block_q",
                               "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
-                    block_q=128, block_k=128, interpret=True):
-    """q: (B, Sq, H, HD); k, v: (B, Skv, KV, HD) -> (B, Sq, H, HD)."""
+                    block_q=128, block_k=128, interpret=None):
+    """q: (B, Sq, H, HD); k, v: (B, Skv, KV, HD) -> (B, Sq, H, HD).
+
+    ``interpret=None`` compiles on a TPU backend and interprets elsewhere."""
     b, sq, h, hd = q.shape
     skv, kv = k.shape[1], k.shape[2]
     g = h // kv
@@ -94,28 +97,31 @@ def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
     kernel = functools.partial(
         _kernel, block_q=block_q, block_k=block_k, n_k=n_k, causal=causal,
         window=window, q_offset=q_offset, scale=scale)
+    # head-major: (B, heads, S, HD)
+    qt, kt, vt = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd),
-                         lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bi, hi, qi, ki, g=g: (bi, ki, hi // g, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bi, hi, qi, ki, g=g: (bi, ki, hi // g, 0)),
+            pl.BlockSpec((None, None, block_q, hd),
+                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((None, None, block_k, hd),
+                         lambda bi, hi, qi, ki, g=g: (bi, hi // g, ki, 0)),
+            pl.BlockSpec((None, None, block_k, hd),
+                         lambda bi, hi, qi, ki, g=g: (bi, hi // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd),
-                               lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, hd), q.dtype),
+        out_specs=pl.BlockSpec((None, None, block_q, hd),
+                               lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=interpret,
-    )(q, k, v)
+        interpret=kernel_interpret(interpret),
+    )(qt, kt, vt)
+    return jnp.swapaxes(out, 1, 2)

@@ -3,15 +3,14 @@
 Each op is differentiable via ``jax.custom_vjp`` whose backward pass
 recomputes through the pure-jnp oracle (``ref.py``) — the standard
 flash-attention trick of trading recompute for never materializing the
-forward's O(S^2) intermediates.  Forward runs the Pallas kernel
-(``interpret=True`` on CPU; compiled on TPU).
+forward's O(S^2) intermediates.  Forward runs the Pallas kernel, compiled
+on a TPU backend and interpreted on any other (``repro.runtime``).
 
 Model code reaches these through ``cfg.attention_impl == "pallas"``.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -21,10 +20,6 @@ from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.decode_attention import decode_attention as _decode_pallas
 from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
 
-# interpret=True executes kernel bodies on CPU; on a real TPU runtime set
-# REPRO_PALLAS_COMPILED=1 to lower them natively.
-_INTERPRET = os.environ.get("REPRO_PALLAS_COMPILED", "0") != "1"
-
 
 # ---------------------------------------------------------------------------
 # flash attention
@@ -32,7 +27,7 @@ _INTERPRET = os.environ.get("REPRO_PALLAS_COMPILED", "0") != "1"
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q, k, v, causal=True, window=0, q_offset=0):
     return _flash_pallas(q, k, v, causal=causal, window=window,
-                         q_offset=q_offset, interpret=_INTERPRET)
+                         q_offset=q_offset)
 
 
 def _fa_fwd(q, k, v, causal, window, q_offset):
@@ -55,7 +50,7 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # decode attention (inference only; no vjp needed, but harmless to add)
 # ---------------------------------------------------------------------------
 def decode_attention(q, k_cache, v_cache, length):
-    return _decode_pallas(q, k_cache, v_cache, length, interpret=_INTERPRET)
+    return _decode_pallas(q, k_cache, v_cache, length)
 
 
 # ---------------------------------------------------------------------------
@@ -63,7 +58,7 @@ def decode_attention(q, k_cache, v_cache, length):
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def ssd_scan(x, dt, a_log, b, c, chunk=128):
-    return _ssd_pallas(x, dt, a_log, b, c, chunk=chunk, interpret=_INTERPRET)
+    return _ssd_pallas(x, dt, a_log, b, c, chunk=chunk)
 
 
 def _ssd_fwd(x, dt, a_log, b, c, chunk):

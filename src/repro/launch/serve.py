@@ -12,7 +12,13 @@
 ``--workers N`` additionally spawns N worker processes that each construct
 an engine from a ``borrow()`` of the parent's published weight proxy —
 on the shm data plane all N resolve zero-copy views of ONE arena mapping
-(no per-worker deep copy of the parameters).
+(no per-worker deep copy of the parameters).  It runs on a CPU backend
+only: a TPU chip serves one process at a time and the parent holds it, so
+on a TPU backend ``--workers`` fails before anything is spawned.
+
+    # full width on one TPU chip (phi4-mini-3.8b fits whole in 16 GB)
+    PYTHONPATH=src python -m repro.launch.serve --arch phi4-mini-3.8b \
+        --preset full --requests 8 --prompt-len 128 --new-tokens 32
 """
 from __future__ import annotations
 
@@ -26,6 +32,7 @@ import numpy as np
 
 from repro.configs import ARCHS
 from repro.launch.train import build_cfg
+from repro.runtime import enable_compile_cache, refuse_child_workers_on_tpu
 from repro.serve.engine import Request, ServeEngine
 
 
@@ -135,6 +142,9 @@ def main() -> None:
                          "via a borrowed arena proxy")
     ap.add_argument("--max-context", type=int, default=None)
     args = ap.parse_args()
+    if args.workers:
+        refuse_child_workers_on_tpu("--workers")
+    enable_compile_cache()
 
     cfg = build_cfg(args.arch, args.preset)
     ckpts = _build_ckpts(args.ckpt_dir) if args.ckpt_dir else None

@@ -4,7 +4,7 @@
         --steps 200 --preset small --workdir /tmp/run1 [--resume]
 
 Presets scale the assigned architecture down for CPU execution while keeping
-its family structure (the full configs are exercised by the dry-run).
+its family structure; ``full`` keeps the published widths.
 """
 from __future__ import annotations
 
@@ -12,6 +12,7 @@ import argparse
 import json
 
 from repro.configs import ARCHS
+from repro.runtime import enable_compile_cache
 from repro.train.optimizer import OptConfig
 from repro.train.trainer import TrainConfig, Trainer
 
@@ -57,6 +58,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--crash-at", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = build_cfg(args.arch, args.preset)
     tc = TrainConfig(steps=args.steps, batch=args.batch, seq=args.seq,

@@ -2,7 +2,7 @@
 
 ``build_model(cfg)`` returns a :class:`Model` namespace:
 
-* ``init(key) -> params``                    (materializes; smoke/reduced only)
+* ``init(key) -> params``                    (materializes the parameters)
 * ``loss(params, batch) -> (loss, metrics)`` (train shapes)
 * ``prefill(params, batch) -> (last_logits, cache)``
 * ``decode_step(params, cache, token, pos) -> (logits, cache)``

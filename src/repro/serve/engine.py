@@ -188,7 +188,9 @@ class ServeEngine:
                 state = ckpts.restore()
                 params = jax.tree.map(jnp.asarray, state["params"])
             else:
-                params = self.model.init(jax.random.key(0))
+                # one program: at full width the random init is GBs, and
+                # op-by-op dispatch would compile each op on its own
+                params = jax.jit(self.model.init)(jax.random.key(0))
         self.params = params
         self.max_batch = int(max_batch)
         self.max_context = int(max_context)

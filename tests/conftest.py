@@ -1,6 +1,9 @@
 import os
 import sys
 
+# the tests run on the CPU: with libtpu installed, JAX would otherwise take
+# the TPU (which belongs to one process at a time)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # NOTE: no XLA_FLAGS here by design — smoke tests and benches must see ONE
 # device; only launch/dryrun.py (a separate process) forces 512.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

@@ -181,3 +181,13 @@ def test_overprovision_math():
     p_ok = sum(math.comb(n, k) * 0.7 ** k * 0.3 ** (n - k)
                for k in range(4, n + 1))
     assert p_ok >= 0.99
+
+
+def test_fl_round_refused_on_tpu_backend(monkeypatch):
+    """The executor's workers jit local training; on a TPU backend the
+    orchestrator (which holds the chip) refuses before dispatching any."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        FLOrchestrator(TINY, FLConfig(), executor=None, store=None)

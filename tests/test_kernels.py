@@ -102,3 +102,27 @@ def test_flash_fully_masked_rows_are_zero():
     q, k, v = qkv(jax.random.key(4), 1, 256, 256, 2, 2, 32, jnp.float32)
     out = flash_attention(q, k, v, causal=True, window=16)
     assert np.isfinite(np.asarray(out)).all()
+
+
+@pytest.mark.parametrize("backend,expected", [("cpu", True), ("gpu", True),
+                                              ("tpu", False)])
+def test_interpret_default_follows_platform(monkeypatch, backend, expected):
+    """``interpret=None`` interprets on every backend but the TPU; an
+    explicit value always wins."""
+    from repro.runtime import kernel_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert kernel_interpret(None) is expected
+    assert kernel_interpret(True) is True
+    assert kernel_interpret(False) is False
+
+
+def test_kernel_on_tpu_backend_is_never_interpreted(monkeypatch):
+    """With the backend steered to TPU, the default path hands Mosaic a
+    compiled kernel — which the CPU backend then refuses — instead of
+    quietly interpreting.  (A shape no other test traces, so the jit cache
+    holds no interpreted program for it.)"""
+    q, k, v = qkv(jax.random.key(5), 1, 120, 120, 2, 1, 8, jnp.float32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="interpret mode"):
+        flash_attention(q, k, v)

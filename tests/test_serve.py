@@ -289,3 +289,22 @@ def test_multi_worker_zero_copy_weight_sharing(engine, tmp_path):
                 p.terminate()
         store.close()
         unregister_store(name)
+
+
+def test_workers_refused_on_tpu_backend(monkeypatch):
+    """``launch/serve.py --workers`` on a TPU backend fails before it builds
+    an engine or spawns a child: the parent would hold the chip."""
+    import jax
+
+    from repro.launch import serve as serve_mod
+
+    def must_not_run(*a, **kw):
+        raise AssertionError("reached engine construction or spawn")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(serve_mod, "ServeEngine", must_not_run)
+    monkeypatch.setattr(serve_mod.mp, "get_context", must_not_run)
+    monkeypatch.setattr("sys.argv", ["serve", "--preset", "tiny",
+                                     "--workers", "2"])
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        serve_mod.main()
