@@ -356,6 +356,7 @@ class KVBlockPool:
         self.dtype = _resolve_dtype(cfg.dtype)
         self._direct = getattr(store.connector, "supports_blocks", False)
         self._blocks: dict[tuple, KVBlock] = {}   # key -> tracked block
+        self._bytes = 0                            # sum of their nbytes
 
     # -- write path ----------------------------------------------------------
     def put_block(self, k, v) -> KVBlock:
@@ -379,6 +380,7 @@ class KVBlockPool:
             self.store.lease(key, self.lease_ttl)   # crashed-owner backstop
         blk = KVBlock(tuple(key), ntok, nbytes)
         self._blocks[blk.key] = blk
+        self._bytes += nbytes
         return blk
 
     def put_prefill(self, k, v) -> list[KVBlock]:
@@ -420,7 +422,9 @@ class KVBlockPool:
         """Drop the owning references (request completion): each block's
         refcount hits zero and the channel evicts/frees its slot."""
         for blk in blocks:
-            self._blocks.pop(blk.key, None)
+            gone = self._blocks.pop(blk.key, None)
+            if gone is not None:
+                self._bytes -= gone.nbytes
             self.store.decref(blk.key)
 
     def touch(self, blocks: list[KVBlock]) -> None:
@@ -442,10 +446,10 @@ class KVBlockPool:
     def _prune(self) -> None:
         dead = [key for key in self._blocks if not self.store.exists(key)]
         for key in dead:
-            self._blocks.pop(key, None)
+            self._bytes -= self._blocks.pop(key).nbytes
 
     def bytes_in_use(self) -> int:
-        return sum(b.nbytes for b in self._blocks.values())
+        return self._bytes
 
     def _ensure_budget(self, nbytes: int) -> None:
         if self.budget_bytes is None:

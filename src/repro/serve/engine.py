@@ -42,6 +42,7 @@ from repro.configs.base import ArchConfig
 from repro.core.proxy import extract, get_factory, is_proxy
 from repro.models.model import build_model
 from repro.models.serve_paths import KVBlockPool, KVPoolExhausted
+from repro.serve.tracing import Tracer
 from repro.train.checkpoints import ProxyCheckpointManager
 
 _EXHAUSTED = object()     # source sentinel: no request will ever come again
@@ -60,8 +61,9 @@ class Completion:
     req_id: str
     tokens: list[int]
     prompt_len: int
-    queued_s: float            # submission -> admission
-    total_s: float             # submission -> completion
+    queued_s: float            # poll -> prefill dispatch
+    total_s: float             # poll -> completion
+    first_token_s: float       # poll -> first token on the host
 
 
 @dataclass
@@ -74,6 +76,7 @@ class _Active:
     flushed: int               # tokens already paged out to KV blocks
     submit_t: float
     admit_t: float
+    first_t: float
     out: list[int] = field(default_factory=list)
     blocks: list = field(default_factory=list)
 
@@ -93,17 +96,16 @@ class _ListSource:
 
 class _StreamSource:
     """Source over a ProxyStream consumer: requests arrive as stream items
-    (optionally proxies — resolved here), end with the producer's close.
-    Polling is non-blocking while rows are busy (a short channel wait) and
-    blocking when the engine is idle."""
+    (optionally proxies, which the engine resolves with
+    :func:`_as_request`), end with the producer's close.  Polling is
+    non-blocking while rows are busy (a short channel wait) and blocking
+    when the engine is idle."""
 
     _POLL_S = 0.002
 
-    def __init__(self, stream, *, timeout: float,
-                 consume: bool = True) -> None:
+    def __init__(self, stream, *, timeout: float) -> None:
         self._stream = stream
         self._timeout = timeout
-        self._consume = consume
         self._pending: deque = deque()
         self._done = False
 
@@ -123,13 +125,13 @@ class _StreamSource:
                 self._done = True      # idle past the deadline: give up
                 return _EXHAUSTED
             return None
-        return _as_request(item, consume=self._consume)
+        return item
 
     def push_back(self, req: Request) -> None:
         self._pending.appendleft(req)
 
 
-def _as_request(item, consume: bool = False) -> Request:
+def _as_request(item) -> Request:
     factory = get_factory(item) if is_proxy(item) else None
     if factory is not None:
         item = extract(item)
@@ -143,8 +145,7 @@ def _as_request(item, consume: bool = False) -> Request:
     else:
         raise TypeError(
             f"cannot interpret stream item as a request: {type(item)}")
-    if consume and factory is not None \
-            and not getattr(factory, "evict", True) \
+    if factory is not None and not getattr(factory, "evict", True) \
             and not getattr(factory, "owned", True):
         # the engine has copied what it needs: free the request's slot now
         # instead of waiting out its lease (keeps the arena's working set
@@ -282,7 +283,7 @@ class ServeEngine:
                 "v": jnp.where(m, jnp.pad(vv[:, perm], pad), cache["v"])}
 
     def _admit_group(self, group: list[tuple[Request, float]],
-                     rows: list[int], state: dict,
+                     rows: list[int], state: dict, tr: Tracer,
                      ) -> tuple[list[_Active], list[Request]]:
         """Admit a same-prompt-length group with ONE batched prefill
         (padded to ``max_batch`` rows so the trace count is bounded by the
@@ -296,22 +297,25 @@ class ServeEngine:
         plen = len(group[0][0].prompt)
         n = len(group)
         B = self.max_batch
-        t0 = time.perf_counter()
-        toks = np.zeros((B, plen), np.int32)
-        for i, (req, _) in enumerate(group):
-            toks[i] = req.prompt
-        batch = {"tokens": jnp.asarray(toks)}
-        if cfg.family == "vlm":
-            batch["vision_emb"] = jnp.zeros(
-                (B, cfg.n_img_tokens, cfg.d_model), jnp.dtype(cfg.dtype))
-        logits, kv = self._prefill(self.params, batch)
-        admit_t = time.perf_counter()
-        self._key, sub = jax.random.split(self._key)
-        temps = np.zeros(B, np.float32)
-        temps[:n] = [req.temperature for req, _ in group]
-        first = np.asarray(self._sample(logits, temps, sub))
-        kh_all = np.asarray(kv["k"])            # (L, B, plen, KV, HD)
-        vh_all = np.asarray(kv["v"])
+        tr.count("prefill_rows", B)
+        with tr.span("engine.admit.prefill"):
+            toks = np.zeros((B, plen), np.int32)
+            for i, (req, _) in enumerate(group):
+                toks[i] = req.prompt
+            batch = {"tokens": jnp.asarray(toks)}
+            if cfg.family == "vlm":
+                batch["vision_emb"] = jnp.zeros(
+                    (B, cfg.n_img_tokens, cfg.d_model), jnp.dtype(cfg.dtype))
+            logits, kv = self._prefill(self.params, batch)
+            admit_t = time.perf_counter()
+            self._key, sub = jax.random.split(self._key)
+            temps = np.zeros(B, np.float32)
+            temps[:n] = [req.temperature for req, _ in group]
+            first = np.asarray(self._sample(logits, temps, sub))
+            first_t = time.perf_counter()
+        with tr.span("engine.admit.kv_to_host"):
+            kh_all = np.asarray(kv["k"])            # (L, B, plen, KV, HD)
+            vh_all = np.asarray(kv["v"])
 
         admitted: list[_Active] = []
         deferred: list[Request] = []
@@ -320,34 +324,37 @@ class ServeEngine:
                 deferred.append(req)
                 continue
             try:
-                blocks = pool.put_prefill(kh_all[:, i], vh_all[:, i])
+                with tr.span("kv.put_prefill"):
+                    blocks = pool.put_prefill(kh_all[:, i], vh_all[:, i])
             except KVPoolExhausted:
                 deferred.append(req)
                 continue
             a = _Active(req=req, row=rows[i], length=plen, flushed=plen,
-                        submit_t=submit_t, admit_t=admit_t, blocks=blocks)
+                        submit_t=submit_t, admit_t=admit_t, first_t=first_t,
+                        blocks=blocks)
             a.out.append(int(first[i]))
             state["tokens"][a.row] = a.out[0]
             state["lengths"][a.row] = plen
             state["temps"][a.row] = req.temperature
             admitted.append(a)
+        tr.count("rows_admitted", len(admitted))
         if admitted:
-            perm = np.arange(B, dtype=np.int32)
-            mask = np.zeros(B, bool)
-            for i, a in enumerate(admitted):
-                perm[a.row] = i
-                mask[a.row] = True
-            state["cache"] = self._scatter(state["cache"], kv["k"], kv["v"],
-                                           jnp.asarray(perm),
-                                           jnp.asarray(mask))
-        state["prefill_s"] += time.perf_counter() - t0
+            with tr.span("engine.admit.scatter"):
+                perm = np.arange(B, dtype=np.int32)
+                mask = np.zeros(B, bool)
+                for i, a in enumerate(admitted):
+                    perm[a.row] = i
+                    mask[a.row] = True
+                state["cache"] = self._scatter(state["cache"], kv["k"],
+                                               kv["v"], jnp.asarray(perm),
+                                               jnp.asarray(mask))
         return admitted, deferred
 
     def _sample(self, logits, temps, key):
         return _sample_tokens(logits, jnp.asarray(temps, jnp.float32), key,
                               vocab=self.cfg.vocab)
 
-    def _flush_blocks(self, a: _Active, cache) -> None:
+    def _flush_blocks(self, a: _Active, cache, tr: Tracer) -> None:
         """Page freshly decoded KV out of the dense row whenever a full
         block has accumulated, so the refcounted pool (not the working
         cache) is the cache's durable home."""
@@ -355,33 +362,43 @@ class ServeEngine:
         bt = pool.block_tokens
         while a.length - a.flushed >= bt:
             e = a.flushed + bt
-            kh = np.asarray(cache["k"][:, a.row, a.flushed:e])
-            vh = np.asarray(cache["v"][:, a.row, a.flushed:e])
-            try:
-                a.blocks.append(pool.put_block(kh, vh))
-            except KVPoolExhausted:
-                return                       # defer: retry next boundary
+            with tr.span("engine.pageout"):
+                kh = np.asarray(cache["k"][:, a.row, a.flushed:e])
+                vh = np.asarray(cache["v"][:, a.row, a.flushed:e])
+                try:
+                    a.blocks.append(pool.put_block(kh, vh))
+                except KVPoolExhausted:
+                    return                   # defer: retry next boundary
             a.flushed = e
 
     def _run_continuous(self, source, sink) -> dict:
         """The continuous-batching loop: admit-as-slots-free, decode the
         whole active set each step with per-row positions, retire each row
-        at its own ``max_new_tokens``."""
+        at its own ``max_new_tokens``.  Returns the loop's counts, its
+        admission and decode time (``prefill_s``/``decode_s``) and the
+        tracer's per-span sums and counters (``spans``/``counters``)."""
+        tr = Tracer()
+        with tr.span("engine.serve", annotate=False):
+            steps, completed = self._serve_loop(source, sink, tr)
+        return {"prefill_s": tr.total_s("engine.admit"),
+                "decode_s": tr.total_s("engine.decode"),
+                "decode_steps": steps, "completed": completed,
+                **tr.summary()}
+
+    def _serve_loop(self, source, sink, tr: Tracer) -> tuple[int, int]:
         B = self.max_batch
         state = {
             "cache": self._alloc_cache(),
             "tokens": np.zeros(B, np.int32),
             "lengths": np.zeros(B, np.int32),    # inactive rows: pos 0,
             "temps": np.zeros(B, np.float32),    # masked + greedy (harmless)
-            "prefill_s": 0.0,
         }
         free_rows = deque(range(B))
         active: dict[int, _Active] = {}
         exhausted = False
-        decode_s = 0.0
         steps = 0
         completed = 0
-        last_touch = time.perf_counter()
+        last_touch = time.perf_counter()           # lease heartbeat clock
 
         kv_starved = False                         # pool full: admissions
                                                    # wait for a retirement
@@ -389,17 +406,21 @@ class ServeEngine:
         def retire(a: _Active) -> None:
             nonlocal completed, kv_starved
             kv_starved = False
-            self.kv_pool().release(a.blocks)      # refcounts -> 0 -> freed
-            now = time.perf_counter()
-            sink(Completion(req_id=a.req.req_id, tokens=a.out,
-                            prompt_len=len(a.req.prompt),
-                            queued_s=a.admit_t - a.submit_t,
-                            total_s=now - a.submit_t))
-            active.pop(a.row)
-            state["lengths"][a.row] = 0
-            state["temps"][a.row] = 0.0
-            state["tokens"][a.row] = 0
-            free_rows.append(a.row)
+            with tr.span("engine.retire"):
+                with tr.span("kv.release"):
+                    self.kv_pool().release(a.blocks)  # refcounts -> 0 -> freed
+                now = time.perf_counter()
+                with tr.span("result.publish"):
+                    sink(Completion(req_id=a.req.req_id, tokens=a.out,
+                                    prompt_len=len(a.req.prompt),
+                                    queued_s=a.admit_t - a.submit_t,
+                                    total_s=now - a.submit_t,
+                                    first_token_s=a.first_t - a.submit_t))
+                active.pop(a.row)
+                state["lengths"][a.row] = 0
+                state["temps"][a.row] = 0.0
+                state["tokens"][a.row] = 0
+                free_rows.append(a.row)
             completed += 1
 
         while True:
@@ -407,11 +428,17 @@ class ServeEngine:
             ready: list[tuple[Request, float]] = []
             while not kv_starved and len(ready) < len(free_rows) \
                     and not exhausted:
-                req = source.poll(block=not active and not ready)
-                if req is _EXHAUSTED:
+                with tr.span("engine.poll") as poll:
+                    item = source.poll(block=not active and not ready)
+                    if item is None or item is _EXHAUSTED:
+                        poll.outcome = "empty"
+                    else:
+                        with tr.span("request.resolve"):
+                            req = _as_request(item)
+                if item is _EXHAUSTED:
                     exhausted = True
                     break
-                if req is None:
+                if item is None:
                     break                          # nothing waiting right now
                 if not req.req_id:
                     req.req_id = uuid.uuid4().hex[:12]
@@ -422,11 +449,13 @@ class ServeEngine:
                         f"max_context {self.max_context}")
                 ready.append((req, time.perf_counter()))
             groups: dict[int, list[tuple[Request, float]]] = {}
-            for item in ready:
-                groups.setdefault(len(item[0].prompt), []).append(item)
+            for entry in ready:
+                groups.setdefault(len(entry[0].prompt), []).append(entry)
             for group in groups.values():
                 rows = [free_rows.popleft() for _ in group]
-                admitted, deferred = self._admit_group(group, rows, state)
+                with tr.span("engine.admit"):
+                    admitted, deferred = self._admit_group(group, rows,
+                                                           state, tr)
                 for row in rows[len(admitted):]:
                     free_rows.append(row)
                 for req in reversed(deferred):     # keep arrival order
@@ -448,14 +477,13 @@ class ServeEngine:
                 continue                           # idle: block in poll()
 
             # -- one decode step over the whole active set ----------------
-            t0 = time.perf_counter()
-            logits, state["cache"] = self._decode(
-                self.params, state["cache"],
-                jnp.asarray(state["tokens"][:, None]),
-                jnp.asarray(state["lengths"]))
-            self._key, sub = jax.random.split(self._key)
-            nxt = np.asarray(self._sample(logits, state["temps"], sub))
-            decode_s += time.perf_counter() - t0
+            with tr.span("engine.decode"):
+                logits, state["cache"] = self._decode(
+                    self.params, state["cache"],
+                    jnp.asarray(state["tokens"][:, None]),
+                    jnp.asarray(state["lengths"]))
+                self._key, sub = jax.random.split(self._key)
+                nxt = np.asarray(self._sample(logits, state["temps"], sub))
             steps += 1
 
             for a in list(active.values()):
@@ -464,20 +492,19 @@ class ServeEngine:
                 tok = int(nxt[a.row])
                 a.out.append(tok)
                 state["tokens"][a.row] = tok
-                self._flush_blocks(a, state["cache"])
+                self._flush_blocks(a, state["cache"], tr)
                 if len(a.out) >= a.req.max_new_tokens:
                     retire(a)
 
             # -- lease heartbeat for long-running requests ----------------
             if self.lease_ttl and \
                     time.perf_counter() - last_touch > self.lease_ttl / 2:
-                pool = self.kv_pool()
-                for a in active.values():
-                    pool.touch(a.blocks)
+                with tr.span("engine.heartbeat"):
+                    pool = self.kv_pool()
+                    for a in active.values():
+                        pool.touch(a.blocks)
                 last_touch = time.perf_counter()
-
-        return {"prefill_s": state["prefill_s"], "decode_s": decode_s,
-                "decode_steps": steps, "completed": completed}
+        return steps, completed
 
     # ------------------------------------------------------------------
     # public API
@@ -539,9 +566,11 @@ class ServeEngine:
                 return
             payload = {"req_id": c.req_id, "tokens": c.tokens,
                        "prompt_len": c.prompt_len,
-                       "queued_s": c.queued_s, "total_s": c.total_s}
+                       "queued_s": c.queued_s, "total_s": c.total_s,
+                       "first_token_s": c.first_token_s}
             meta = {"req_id": c.req_id, "n_tokens": len(c.tokens),
-                    "queued_s": c.queued_s, "total_s": c.total_s}
+                    "queued_s": c.queued_s, "total_s": c.total_s,
+                    "first_token_s": c.first_token_s}
             if data_store is not None:
                 producer.append(data_store.proxy(payload, evict=True,
                                                  ttl=result_ttl),
@@ -584,9 +613,9 @@ class ServeEngine:
                 jnp.dtype(cfg.dtype))
         n_new = max(r.max_new_tokens for r in reqs)
 
-        t0 = time.perf_counter()
-        logits, cache = self._prefill(self.params, batch)
-        prefill_s = time.perf_counter() - t0
+        tr = Tracer()
+        with tr.span("engine.admit"):
+            logits, cache = self._prefill(self.params, batch)
 
         # extend lockstep attention caches for the generated tokens (the
         # state-cache/ring families this path serves; the continuous
@@ -601,23 +630,23 @@ class ServeEngine:
 
         out: list[list[int]] = [[] for _ in reqs]
         temps = np.asarray([r.temperature for r in reqs], np.float32)
-        t0 = time.perf_counter()
         for t in range(n_new):
-            self._key, sub = jax.random.split(self._key)
-            nxt = self._sample(logits, temps, sub)[:, None]
-            for i, token in enumerate(np.asarray(nxt)[:, 0]):
-                if t < reqs[i].max_new_tokens:
-                    out[i].append(int(token))
-            if all(len(out[i]) >= r.max_new_tokens
-                   for i, r in enumerate(reqs)):
-                break
-            logits, cache = self._decode(self.params, cache, nxt,
-                                         jnp.asarray(plen + t, jnp.int32))
-        decode_s = time.perf_counter() - t0
+            with tr.span("engine.decode"):
+                self._key, sub = jax.random.split(self._key)
+                nxt = self._sample(logits, temps, sub)[:, None]
+                for i, token in enumerate(np.asarray(nxt)[:, 0]):
+                    if t < reqs[i].max_new_tokens:
+                        out[i].append(int(token))
+                if all(len(out[i]) >= r.max_new_tokens
+                       for i, r in enumerate(reqs)):
+                    break
+                logits, cache = self._decode(self.params, cache, nxt,
+                                             jnp.asarray(plen + t, jnp.int32))
+        decode_s = tr.total_s("engine.decode")
         n_tok = sum(len(o) for o in out)
         return {"outputs": out,
                 "completions": [],
-                "prefill_s": prefill_s,
+                "prefill_s": tr.total_s("engine.admit"),
                 "decode_s": decode_s,
                 "tokens_per_s": n_tok / max(decode_s, 1e-9)}
 

@@ -220,6 +220,113 @@ def test_serve_stream_roundtrip(engine, shm_store):
     assert metas == {rid: len(toks) for rid, toks in want.items()}
 
 
+def test_pool_byte_count_follows_puts_releases_and_reclaims(shm_store):
+    """``bytes_in_use`` is a running total: after every put, release and
+    lease reclaim it equals the sum over the blocks the pool tracks."""
+    pool = KVBlockPool(shm_store, CFG, block_tokens=8, budget_bytes=None,
+                       lease_ttl=0.05)
+
+    def summed():
+        return sum(b.nbytes for b in pool._blocks.values())
+
+    k = np.zeros((CFG.n_layers, 8, CFG.n_kv_heads, CFG.hd), np.float32)
+    short = k[:, :3]
+    kept = pool.put_prefill(np.concatenate([k, short], 1),
+                            np.concatenate([k, short], 1))   # 8 + 3 tokens
+    orphan = pool.put_block(k, k)
+    assert pool.bytes_in_use() == summed() == \
+        sum(b.nbytes for b in kept) + orphan.nbytes
+    pool.release(kept[:1])
+    pool.release(kept[:1])                   # a second release counts once
+    assert pool.bytes_in_use() == summed() == kept[1].nbytes + orphan.nbytes
+    time.sleep(0.12)                         # every lease lapses
+    pool.sweep()
+    assert pool.bytes_in_use() == summed() == 0
+    assert pool.stats()["n_blocks"] == 0
+
+
+# ---------------------------------------------------------------------------
+# the serve loop's spans and counters
+# ---------------------------------------------------------------------------
+def _serve(engine, store, reqs, **kw):
+    def feed():
+        prod = store.stream_producer("req")
+        for r in reqs:
+            prod.append(store.proxy(
+                {"prompt": r.prompt, "max_new_tokens": r.max_new_tokens,
+                 "req_id": r.req_id}, evict=True))
+        prod.close()
+
+    t = threading.Thread(target=feed)
+    t.start()
+    stats = engine.serve_stream(store, "req", "res", data_store=store,
+                                timeout=30.0, **kw)
+    t.join(timeout=30.0)
+    assert not t.is_alive()
+    return stats
+
+
+def test_serve_stats_agree_with_spans_and_counters(engine, shm_store):
+    """The loop's stats read from its tracer: ``decode_s`` is the
+    ``engine.decode`` total over one span per decode step, admission
+    counts every prefilled row, and no parent span is shorter than the
+    children it holds."""
+    rng = np.random.default_rng(41)
+    reqs = [Request(prompt=_prompt(rng, n), max_new_tokens=m,
+                    req_id=f"st-{i}")
+            for i, (n, m) in enumerate([(5, 20), (9, 11), (5, 3), (12, 17),
+                                        (9, 9), (7, 1)])]
+    stats = _serve(engine, shm_store, reqs)
+    spans, counters = stats["spans"], stats["counters"]
+    assert stats["completed"] == len(reqs)
+    assert spans["engine.decode"]["n"] == stats["decode_steps"] > 0
+    assert stats["decode_s"] == spans["engine.decode"]["total_s"]
+    assert stats["prefill_s"] == spans["engine.admit"]["total_s"]
+    assert counters["rows_admitted"] == stats["completed"]
+    # one engine.admit span per admission group, of max_batch rows each
+    assert counters["prefill_rows"] == \
+        spans["engine.admit"]["n"] * engine.max_batch
+    assert set(counters) == {"prefill_rows", "rows_admitted"}
+    assert spans["engine.retire"]["n"] == spans["kv.release"]["n"] == \
+        spans["result.publish"]["n"] == len(reqs)
+    assert spans["request.resolve"]["n"] == len(reqs)
+    assert spans["engine.poll"]["n"] >= len(reqs)
+    assert spans["engine.poll_empty"]["n"] >= 1   # the stream's close
+    assert spans["engine.pageout"]["n"] >= 1       # 20 tokens, 8 a block
+    assert spans["engine.serve"]["n"] == 1
+    assert spans["engine.serve"]["parent"] is None
+    children: dict[str, float] = {}
+    for name, rec in spans.items():
+        if rec["parent"] is not None:
+            children[rec["parent"]] = children.get(rec["parent"], 0.0) \
+                + rec["total_s"]
+    assert set(children) >= {"engine.serve", "engine.admit", "engine.poll",
+                             "engine.retire"}
+    for parent, child_s in children.items():
+        assert spans[parent]["total_s"] >= child_s - 1e-9, parent
+        assert spans[parent]["self_s"] == pytest.approx(
+            spans[parent]["total_s"] - child_s, abs=1e-6)
+
+
+def test_first_token_stamp_is_published(engine, shm_store):
+    """Each completion carries ``first_token_s`` (poll to the first token
+    on the host), between ``queued_s`` and ``total_s``, in the payload
+    and in the event metadata a tap reads."""
+    rng = np.random.default_rng(43)
+    reqs = [Request(prompt=_prompt(rng, 6), max_new_tokens=4,
+                    req_id=f"ft-{i}") for i in range(3)]
+    _serve(engine, shm_store, reqs, result_groups=("metrics",))
+    got = {}
+    for item in shm_store.stream_consumer("res", timeout=10.0):
+        c = extract(item) if is_proxy(item) else item
+        got[c["req_id"]] = c
+        assert c["queued_s"] <= c["first_token_s"] <= c["total_s"]
+    assert set(got) == {r.req_id for r in reqs}
+    with metrics_tap(shm_store, "res", timeout=10.0) as tap:
+        metas = {m["req_id"]: m["first_token_s"] for m in tap}
+    assert metas == {rid: c["first_token_s"] for rid, c in got.items()}
+
+
 # ---------------------------------------------------------------------------
 # multi-worker zero-copy weight sharing: N processes, ONE arena mapping
 # ---------------------------------------------------------------------------
