@@ -94,27 +94,33 @@ def decoder_decode_step(params, cache, token, pos, cfg):
     """token: (B, 1) int32; pos: next-position index — a scalar int32
     (lockstep batch: every row at the same position) or a ``(B,)`` vector
     (continuous batching: per-row positions; each row's KV is left-aligned
-    in its cache row and the new entry scatters to ``pos[b]``)."""
+    in its cache row and the new entry scatters to ``pos[b]``).
+
+    The stacked caches ride in the layer scan's carry and each layer writes
+    its new entry into them in place; as scanned outputs they would be
+    rebuilt in a loop buffer and copied whole into the result every step."""
     bsz = token.shape[0]
     x = params["tok"]["emb"][token]
     pos = jnp.asarray(pos)
     per_row = pos.ndim == 1
     positions = pos[:, None] if per_row else pos[None]
-    w = cache["k"].shape[2]
-    slot = pos % w if cfg.sliding_window else pos
+    n_layers, w = cache["k"].shape[0], cache["k"].shape[2]
+    # a lockstep position writes through the same one-token-per-row scatter:
+    # a dynamic-update-slice there makes the TPU compiler re-lay out (copy)
+    # the whole carried cache
+    slot = jnp.broadcast_to(pos % w if cfg.sliding_window else pos, (bsz,))
     length = jnp.minimum(pos + 1, w)
     rows = jnp.arange(bsz)
 
-    def body(x, p_kv):
-        p, kc, vc = p_kv
+    def body(carry, p_i):
+        x, k_all, v_all = carry
+        p, i = p_i
         h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
         q, k, v = L.attn_qkv(p["attn"], h, cfg, positions)
-        if per_row:
-            kc = kc.at[rows, slot].set(k[:, 0])
-            vc = vc.at[rows, slot].set(v[:, 0])
-        else:
-            kc = jax.lax.dynamic_update_slice_in_dim(kc, k, slot, axis=1)
-            vc = jax.lax.dynamic_update_slice_in_dim(vc, v, slot, axis=1)
+        k_all = k_all.at[i, rows, slot].set(k[:, 0])
+        v_all = v_all.at[i, rows, slot].set(v[:, 0])
+        kc = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
+        vc = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
         o = L.decode_attention(q, kc, vc, length, cfg)
         x = x + o.reshape(bsz, 1, -1) @ p["attn"]["wo"]
         h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
@@ -122,10 +128,10 @@ def decoder_decode_step(params, cache, token, pos, cfg):
             y, _ = moe_decode(p["moe"], h2, cfg)
         else:
             y = L.mlp_block(p["mlp"], h2, cfg)
-        return x + y, (kc, vc)
+        return (x + y, k_all, v_all), None
 
-    x, (ks, vs) = _layer_scan(body, x, (params["layers"], cache["k"],
-                                        cache["v"]), cfg)
+    (x, ks, vs), _ = _layer_scan(body, (x, cache["k"], cache["v"]),
+                                 (params["layers"], jnp.arange(n_layers)), cfg)
     h = L.rms_norm(x, params["tok"]["ln_f"], cfg.norm_eps)
     logits = L.unembed(params["tok"], h, cfg)[:, 0]
     return logits.astype(jnp.float32), {"k": ks, "v": vs}

@@ -4,8 +4,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from hlo_ops import cache_aliases, ops_of_shape
 from repro.configs import ARCHS, SHAPES, get_arch, shape_applicable
 from repro.models.model import build_model
+from repro.serve.engine import ServeEngine
 
 
 def tiny_batch(cfg, B=2, S=32, with_labels=True, key=0):
@@ -120,16 +122,81 @@ def test_ssd_chunk_invariance():
     assert abs(float(l1) - float(l2)) < 1e-5
 
 
-def test_scan_vs_unrolled_equivalence():
-    for arch in ("qwen2.5-14b", "mamba2-2.7b"):
-        cfg = ARCHS[arch].reduced().replace(dtype="float32")
-        m_scan = build_model(cfg)
-        m_loop = build_model(cfg.replace(scan_layers=False))
-        params = m_scan.init(jax.random.key(0))
+@pytest.mark.parametrize("step,arch", [
+    ("loss", "qwen2.5-14b"), ("loss", "mamba2-2.7b"),
+    ("decode", "qwen2.5-14b"), ("decode", "qwen3-moe-30b-a3b")])
+def test_scan_vs_unrolled_equivalence(step, arch):
+    cfg = ARCHS[arch].reduced().replace(dtype="float32")
+    m_scan = build_model(cfg)
+    m_loop = build_model(cfg.replace(scan_layers=False))
+    params = m_scan.init(jax.random.key(0))
+    if step == "loss":
         batch = tiny_batch(cfg)
         l1, _ = m_scan.loss(params, batch)
         l2, _ = m_loop.loss(params, batch)
         assert abs(float(l1) - float(l2)) < 1e-5
+        return
+    # per-row positions into a cache carried through the layers
+    B, S = 3, 24
+    shape = (cfg.n_layers, B, S, cfg.n_kv_heads, cfg.hd)
+    ks = jax.random.split(jax.random.key(1), 3)
+    cache = {"k": jax.random.normal(ks[0], shape),
+             "v": jax.random.normal(ks[1], shape)}
+    tok = jax.random.randint(ks[2], (B, 1), 0, cfg.vocab)
+    pos = jnp.asarray([2, 9, S - 1], jnp.int32)
+    got = m_scan.decode_step(params, cache, tok, pos)
+    want = m_loop.decode_step(params, cache, tok, pos)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_continuous_decode_matches_full_forward():
+    """Rows at different lengths decode several steps into one donated
+    cache, as the continuous engine runs them: every step's logits equal
+    the full forward over that row's prefix."""
+    cfg = ARCHS["qwen2.5-14b"].reduced().replace(dtype="float32")
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    lens, steps, S = (5, 11, 18), 4, 32
+    toks = jax.random.randint(jax.random.key(2), (len(lens), S), 0,
+                              cfg.vocab)
+    shape = (cfg.n_layers, len(lens), S, cfg.n_kv_heads, cfg.hd)
+    cache = {"k": jnp.zeros(shape), "v": jnp.zeros(shape)}
+    for row, n in enumerate(lens):       # left-aligned, as the engine keeps
+        _, kv = model.prefill(params, {"tokens": toks[row:row + 1, :n]})
+        cache = {name: c.at[:, row, :n].set(kv[name][:, 0])
+                 for name, c in cache.items()}
+    step = jax.jit(model.decode_step, donate_argnums=(1,))
+    prefill = jax.jit(model.prefill)
+    pos = np.asarray(lens, np.int32)
+    for _ in range(steps):
+        tok = np.asarray(toks)[np.arange(len(lens)), pos][:, None]
+        dec, cache = step(params, cache, jnp.asarray(tok), jnp.asarray(pos))
+        for row, p in enumerate(pos):
+            full, _ = prefill(params, {"tokens": toks[row:row + 1, :p + 1]})
+            np.testing.assert_allclose(np.asarray(dec[row]),
+                                       np.asarray(full[0]),
+                                       rtol=2e-3, atol=2e-3)
+        pos = pos + 1
+
+
+@pytest.mark.parametrize("pos_shape", [(4,), ()], ids=["per_row", "scalar"])
+@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "qwen2.5-14b"],
+                         ids=["tied", "biased"])
+def test_engine_decode_updates_the_cache_in_place(arch, pos_shape):
+    """The engine's compiled decode step writes each new entry into the
+    donated cache: no copy of the whole stacked cache, and no rebuilt
+    stack written back into it."""
+    cfg = ARCHS[arch].reduced()
+    engine = ServeEngine(cfg, max_batch=4, max_context=64)
+    cache = engine._alloc_cache()
+    txt = engine._decode.lower(
+        engine.params, cache, jnp.zeros((4, 1), jnp.int32),
+        jnp.zeros(pos_shape, jnp.int32)).compile().as_text()
+    assert ops_of_shape(txt, ("copy", "dynamic-update-slice"),
+                        cache["k"].shape) == []
+    assert len(cache_aliases(txt, engine.params)) == 2
 
 
 def test_vlm_loss_masks_image_positions():

@@ -15,9 +15,11 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from hlo_ops import cache_aliases, ops_of_shape
 from repro.configs import ARCHS
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
@@ -117,3 +119,30 @@ def test_phi4_decode_step_compiles_and_fits_one_chip(one_chip):
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert m.argument_size_in_bytes > 9 * 10**9      # weights + cache
     assert total < V5E_HBM_BYTES, total
+
+
+@pytest.mark.parametrize("arch,n_layers", [("phi4-mini-3.8b", 32),
+                                           ("qwen2.5-14b", 12)])
+@pytest.mark.parametrize("pos_shape", [(16,), ()], ids=["per_row", "scalar"])
+def test_decode_step_updates_the_cache_in_place(one_chip, arch, n_layers,
+                                                pos_shape):
+    """At the served size (16 rows x 1024 positions; qwen2.5-14b as a
+    12-layer pipeline stage) the decode step writes each new entry into
+    the donated cache: no copy of the whole stacked cache, no rebuilt
+    stack written back into it, and no temporary of its size."""
+    cfg = ARCHS[arch].replace(n_layers=n_layers)
+    batch, context = 16, 1024
+    params = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), abstract_params(cfg))
+    shape = (cfg.n_layers, batch, context, cfg.n_kv_heads, cfg.hd)
+    cache = {"k": _sds(shape, cfg.dtype, one_chip),
+             "v": _sds(shape, cfg.dtype, one_chip)}
+    step = jax.jit(build_model(cfg).decode_step, donate_argnums=(1,))
+    compiled = step.lower(params, cache,
+                          _sds((batch, 1), "int32", one_chip),
+                          _sds(pos_shape, "int32", one_chip)).compile()
+    txt = compiled.as_text()
+    assert ops_of_shape(txt, ("copy", "dynamic-update-slice"), shape) == []
+    assert len(cache_aliases(txt, params)) == 2
+    cache_bytes = jnp.dtype(cfg.dtype).itemsize * int(np.prod(shape))
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes
