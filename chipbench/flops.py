@@ -1,4 +1,4 @@
-"""Useful operations of a dense decoder, counted from shapes.
+"""Useful operations of a served decoder, counted from shapes.
 
 Useful means what the served requests need: real rows and tokens only,
 attention over each token's valid context (causal), one row of output-head
@@ -6,42 +6,36 @@ logits per generated token over the real vocabulary.  Padded rows, masked
 positions and padded vocabulary rows are never counted, so the count is
 the same for the same work whatever computes it.  A multiply-add is two
 operations; norms, rotary, softmax and sampling are left out (under 1% at
-these widths).
+these widths).  The architecture's module gives the per-token terms: the
+weights a token multiplies, and the operations per position it attends to.
 """
 from __future__ import annotations
 
-from chipbench.spec import ModelSpec
+from chipbench import arch
 
 
-def layer_weights(spec: ModelSpec) -> int:
-    """Weights a token multiplies in one layer (projections and MLP)."""
-    d, hd = spec.d_model, spec.head_dim
-    q, kv = spec.n_heads * hd, spec.n_kv_heads * hd
-    return d * q + 2 * d * kv + q * d + 3 * d * spec.d_ff
-
-
-def token_flops(spec: ModelSpec, context: int) -> int:
+def token_flops(spec, context: int) -> int:
     """One token through every layer, attending to ``context`` positions
-    (itself included): projections and MLP, scores and weighted values."""
-    attn = 4 * spec.n_heads * spec.head_dim * context
-    return spec.n_layers * (2 * layer_weights(spec) + attn)
+    (itself included): weight matmuls, scores and weighted values."""
+    mod = arch.of(spec)
+    return 2 * mod.token_weights(spec) + mod.attention_ops(spec) * context
 
 
-def head_flops(spec: ModelSpec) -> int:
+def head_flops(spec) -> int:
     """One row of output-head logits over the real vocabulary."""
     return 2 * spec.d_model * spec.vocab
 
 
-def prefill_flops(spec: ModelSpec, prompt_len: int) -> int:
+def prefill_flops(spec, prompt_len: int) -> int:
     """A prompt's tokens, causal (token i attends to i + 1 positions), and
     the logits of its last position, which give the first served token."""
+    mod = arch.of(spec)
     p = prompt_len
-    attn = 4 * spec.n_heads * spec.head_dim * p * (p + 1) // 2
-    return (spec.n_layers * (2 * layer_weights(spec) * p + attn)
-            + head_flops(spec))
+    return (2 * mod.token_weights(spec) * p
+            + mod.attention_ops(spec) * p * (p + 1) // 2 + head_flops(spec))
 
 
-def decode_flops(spec: ModelSpec, prompt_len: int, n_out: int) -> int:
+def decode_flops(spec, prompt_len: int, n_out: int) -> int:
     """The decode steps after the first served token: the step that gives
     token j (j >= 1) runs token j - 1 at position prompt_len + j - 1, which
     attends to prompt_len + j positions."""
@@ -49,6 +43,6 @@ def decode_flops(spec: ModelSpec, prompt_len: int, n_out: int) -> int:
                for j in range(1, n_out))
 
 
-def request_flops(spec: ModelSpec, prompt_len: int, n_out: int) -> int:
+def request_flops(spec, prompt_len: int, n_out: int) -> int:
     return prefill_flops(spec, prompt_len) + decode_flops(spec, prompt_len,
                                                           n_out)

@@ -20,9 +20,9 @@ from contextlib import ExitStack
 import jax
 import numpy as np
 
-from chipbench import loadgen, reference, trace_reduce
+from chipbench import arch, loadgen, reference, trace_reduce
 from chipbench import weights as W
-from chipbench.spec import ROOT, Cell, ModelSpec, metric_reader
+from chipbench.spec import ROOT, Cell, metric_reader
 
 LOWERING = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 REQ_TOPIC, RES_TOPIC, CLIENT = "requests", "results", "client"
@@ -56,25 +56,12 @@ class CompileLog:
         return sum(t0 <= t <= t1 for t in self.times)
 
 
-def arch_config(spec: ModelSpec):
-    from repro.configs.base import ArchConfig
-
-    return ArchConfig(
-        name=spec.name, family="dense", n_layers=spec.n_layers,
-        d_model=spec.d_model, n_heads=spec.n_heads,
-        n_kv_heads=spec.n_kv_heads, d_ff=spec.d_ff, vocab=spec.vocab,
-        head_dim=spec.head_dim, qkv_bias=spec.qkv_bias,
-        tie_embeddings=spec.tie_embeddings, rope_theta=spec.rope_theta,
-        norm_eps=spec.norm_eps, dtype=spec.dtype,
-        pad_vocab_to=spec.vocab_multiple)
-
-
-def build_engine(spec: ModelSpec, seed: int):
+def build_engine(spec, seed: int):
     from repro.serve.engine import ServeEngine
 
     params = W.make_params(spec, seed)
     jax.block_until_ready(params)
-    cfg = arch_config(spec)
+    cfg = arch.of(spec).arch_config(spec)
     return ServeEngine(cfg, params=params, max_batch=spec.max_batch,
                        max_context=spec.max_context,
                        block_tokens=spec.block_tokens,
@@ -91,16 +78,17 @@ def _new_store(stack: ExitStack):
     return store
 
 
-def warm_up(engine, store, spec: ModelSpec, shapes: list[int]) -> None:
+def warm_up(engine, store, spec, shapes: list[int]) -> None:
     """Compile every program the window will run: one request per prompt
     shape through ``serve_stream`` (prefill, scatter, decode, sampling, the
     stream plane, a KV page-out), then every page-out slice the window can
-    take.  The engine pages KV out of its dense cache (layers, rows,
-    positions, KV heads, head size) one block at a time with a slice at
-    Python-int bounds, one executable per (row, offset); the same slices of
-    an array of that shape compile them all.  Should the engine page out
-    another way, these slices are warmed for nothing, and any compile left
-    in the window shows in ``compiles_in_window``."""
+    take.  The engine pages KV out of its cache (layers, rows, positions,
+    then the architecture's own axes: its module's ``cache_shape``) one
+    block at a time with a slice at Python-int bounds, one executable per
+    (row, offset); the same slices of an array of that shape compile them
+    all.  Should the engine page out another way, these slices are warmed
+    for nothing, and any compile left in the window shows in
+    ``compiles_in_window``."""
     import jax.numpy as jnp
     from repro.serve.engine import Request
 
@@ -114,8 +102,7 @@ def warm_up(engine, store, spec: ModelSpec, shapes: list[int]) -> None:
         raise RuntimeError(f"warm-up returned {sorted(done)}")
     _log(f"set-up: {len(reqs)} prompt shapes served in "
          f"{time.perf_counter() - t0:.3f} s")
-    cache = jnp.zeros((spec.n_layers, spec.max_batch, spec.max_context,
-                       spec.n_kv_heads, spec.head_dim), jnp.dtype(spec.dtype))
+    cache = jnp.zeros(arch.of(spec).cache_shape(spec), jnp.dtype(spec.dtype))
     for row in range(spec.max_batch):
         for s in range(min(shapes), spec.max_context - bt + 1, bt):
             cache[:, row, s:s + bt].block_until_ready()

@@ -1,14 +1,12 @@
-"""Plain float32 forward of a dense decoder, and the comparison that decides
-``correct`` for a served model.
+"""Plain float32 forward of a served model, and the comparison that decides
+``correct`` for it.
 
-The reference follows the published layer equations of the configurations
-in ``chipbench/configs`` (Phi-3 / Qwen2 style): RMSNorm with a gain, GQA
-attention with rotary embeddings (rotate-half pairing, base
-``rope_theta``), optional q/k/v biases, a SwiGLU MLP, a final RMSNorm and
-a tied or untied output head.  It computes in float32 at ``HIGHEST``
-matmul precision, layer by layer, with weights remade from the seed by
-``chipbench.weights`` one layer at a time: it imports nothing of the
-program and takes nothing the program made.
+It embeds the tokens, walks the architecture's stacks of layers in order
+and applies its final norm and output head, each as the architecture's
+module (``chipbench/arch/<name>.py``) writes the published equations.  It
+computes in float32 at ``HIGHEST`` matmul precision, layer by layer, with
+weights remade from the seed by ``chipbench.weights`` one layer at a time:
+it imports nothing of the program and takes nothing the program made.
 
 :func:`served_gaps` runs every sequence (prompt + served tokens) through
 the whole forward once and reads, at each served position, how far the
@@ -26,15 +24,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import arch
 from chipbench import weights as W
-from chipbench.spec import ModelSpec
 
 HI = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0                 # largest finite float8_e4m3fn
 HEAD_ROWS = 512                # positions per output-head block
 
 
-def _mm(a, w):
+def mm(a, w):
     return jnp.matmul(a, w.astype(jnp.float32), precision=HI)
 
 
@@ -44,16 +42,16 @@ def _q8(a, axis):
     return (a / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _mm8(a, w):
+def mm8(a, w):
     return jnp.matmul(_q8(a, -1), _q8(w.astype(jnp.float32), 0), precision=HI)
 
 
-def _rms(x, gain, eps):
+def rms(x, gain, eps):
     x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
     return x * (1.0 + gain.astype(jnp.float32))
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x: (n, T, heads, hd) at positions 0..T-1; pairs dim i with i+hd/2."""
     t, hd = x.shape[1], x.shape[-1]
     half = hd // 2
@@ -64,53 +62,31 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@partial(jax.jit, static_argnames=("spec", "f8"))
-def _layer(x, w, *, spec: ModelSpec, f8: bool):
-    """One decoder layer over x: (n, T, D) float32, causal from position 0."""
-    mm = _mm8 if f8 else _mm
-    n, t, _ = x.shape
-    h_, kv, hd = spec.n_heads, spec.n_kv_heads, spec.head_dim
-    a = w["attn"]
-    h = _rms(x, w["ln1"], spec.norm_eps)
-    q, k, v = mm(h, a["wq"]), mm(h, a["wk"]), mm(h, a["wv"])
-    if spec.qkv_bias:
-        q = q + a["bq"].astype(jnp.float32)
-        k = k + a["bk"].astype(jnp.float32)
-        v = v + a["bv"].astype(jnp.float32)
-    q = _rope(q.reshape(n, t, h_, hd), spec.rope_theta)
-    k = _rope(k.reshape(n, t, kv, hd), spec.rope_theta)
-    v = v.reshape(n, t, kv, hd)
-    q = q.reshape(n, t, kv, h_ // kv, hd)
-    s = jnp.einsum("nqkgh,nskh->nkgqs", q, k, precision=HI) / np.sqrt(hd)
-    causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-    s = jnp.where(causal, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("nkgqs,nskh->nqkgh", p, v, precision=HI)
-    x = x + mm(o.reshape(n, t, h_ * hd), a["wo"])
-    m = w["mlp"]
-    h = _rms(x, w["ln2"], spec.norm_eps)
-    g = jax.nn.silu(mm(h, m["w_gate"])) * mm(h, m["w_up"])
-    return x + mm(g, m["w_down"])
+@partial(jax.jit, static_argnames=("spec", "stack", "f8"))
+def _layer(x, w, *, spec, stack: str, f8: bool):
+    """One layer of ``stack`` over x: (n, T, D) float32."""
+    return arch.of(spec).layer(x, w, spec=spec, stack=stack,
+                               mm=mm8 if f8 else mm)
 
 
 @partial(jax.jit, static_argnames=("spec", "f8"))
-def _head_block(h, targets, head, *, spec: ModelSpec, f8: bool):
+def _head_block(h, targets, head, *, spec, f8: bool):
     """Logits of a block of positions over the real vocabulary: the best
     logit, the target's logit and the argmax."""
-    logits = (_mm8 if f8 else _mm)(h, head)[:, :spec.vocab]
+    logits = (mm8 if f8 else mm)(h, head)[:, :spec.vocab]
     best = jnp.max(logits, axis=-1)
     tgt = jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
     return best, tgt, jnp.argmax(logits, axis=-1), logits
 
 
 @partial(jax.jit, static_argnames=("spec",))
-def _embed(tokens, tok, *, spec: ModelSpec):
+def _embed(tokens, tok, *, spec):
     return tok["emb"][tokens].astype(jnp.float32)
 
 
 @partial(jax.jit, static_argnames=("spec",))
-def _final(x, tok, *, spec: ModelSpec):
-    return _rms(x, tok["ln_f"], spec.norm_eps).reshape(-1, spec.d_model)
+def _final(x, tok, *, spec):
+    return arch.of(spec).final_norm(x, tok, spec).reshape(-1, spec.d_model)
 
 
 @jax.jit
@@ -118,32 +94,30 @@ def _gap_at(logits, best, idx):
     return best - jnp.take_along_axis(logits, idx[:, None], axis=-1)[:, 0]
 
 
-def _hidden(spec: ModelSpec, words, toks: np.ndarray, modes) -> tuple:
+def _hidden(spec, words, toks: np.ndarray, modes) -> tuple:
     """Final normed hidden states, (n * T, D) float32, for each mode
     (False: float32, True: float8 control), and the token parameters."""
     tok = W.token_params(words, spec)
     x = _embed(jnp.asarray(toks), tok, spec=spec)
     xs = {f8: x for f8 in modes}
-    for layer in range(spec.n_layers):
-        w = W.layer_params(words, jnp.int32(layer), spec)
-        xs = {f8: _layer(v, w, spec=spec, f8=f8) for f8, v in xs.items()}
-        del w
+    for stack, depth in arch.of(spec).stacks(spec).items():
+        for layer in range(depth):
+            w = W.layer_params(words, jnp.int32(layer), spec, stack)
+            xs = {f8: _layer(v, w, spec=spec, stack=stack, f8=f8)
+                  for f8, v in xs.items()}
+            del w
     return {f8: _final(v, tok, spec=spec) for f8, v in xs.items()}, tok
 
 
-def _head(spec: ModelSpec, tok):
-    return tok["emb"].T if spec.tie_embeddings else tok["unemb"]
-
-
-def logits(spec: ModelSpec, seed: int, toks: np.ndarray) -> np.ndarray:
+def logits(spec, seed: int, toks: np.ndarray) -> np.ndarray:
     """Reference logits over the real vocabulary at every position of
     ``toks`` (n, T) — for tests at small sizes."""
     hs, tok = _hidden(spec, W.seed_words(seed), toks, (False,))
-    out = _mm(hs[False], _head(spec, tok))[:, :spec.vocab]
+    out = mm(hs[False], arch.of(spec).head(spec, tok))[:, :spec.vocab]
     return np.asarray(out).reshape(*toks.shape, spec.vocab)
 
 
-def served_gaps(spec: ModelSpec, seed: int, seqs, *, length: int,
+def served_gaps(spec, seed: int, seqs, *, length: int,
                 control: bool = False) -> dict:
     """Reference readings over served sequences.
 
@@ -165,7 +139,7 @@ def served_gaps(spec: ModelSpec, seed: int, seqs, *, length: int,
     targets[:, :-1] = toks[:, 1:]
     hs, tok = _hidden(spec, W.seed_words(seed), toks,
                       (False, True) if control else (False,))
-    head = _head(spec, tok)
+    head = arch.of(spec).head(spec, tok)
     flat_t = jnp.asarray(targets.reshape(-1))
     rows = n * length
     gap = np.zeros(rows, np.float64)

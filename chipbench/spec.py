@@ -3,7 +3,8 @@
 Every configuration, traffic mix, cell and per-layer metric lives in a file
 of its own, found by its name:
 
-* ``chipbench/configs/<config>.json``  model sizes, serving sizes, source;
+* ``chipbench/configs/<config>.json``  model sizes, serving sizes, source,
+  and the architecture module that reads them (``chipbench/arch``);
 * ``chipbench/traffic/<traffic>.json`` parameters of one traffic mix;
 * ``chipbench/cells/<workload>.json``  what belongs to one cell alone
   (its offered rate, its correctness limit);
@@ -17,64 +18,19 @@ import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
+
+from chipbench import arch
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
 BENCHMARK_JSON = ROOT / "BENCHMARK.json"
 
 
-@dataclass(frozen=True)
-class ModelSpec:
-    """A dense decoder as served: published sizes plus the serving sizes.
-
-    Keys of the configuration file's ``model`` group follow the published
-    ``config.json`` (Hugging Face names)."""
-
-    name: str
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    qkv_bias: bool
-    tie_embeddings: bool
-    rope_theta: float
-    norm_eps: float
-    dtype: str
-    vocab_multiple: int      # the program pads embedding rows to a multiple
-    max_batch: int
-    max_context: int
-    block_tokens: int
-
-    @property
-    def vocab_rows(self) -> int:
-        return -(-self.vocab // self.vocab_multiple) * self.vocab_multiple
-
-
-def model_spec(conf: dict) -> ModelSpec:
-    m, s = conf["model"], conf["serving"]
-    heads = m["num_attention_heads"]
-    return ModelSpec(
-        name=conf["name"],
-        n_layers=m["num_hidden_layers"],
-        d_model=m["hidden_size"],
-        n_heads=heads,
-        n_kv_heads=m["num_key_value_heads"],
-        head_dim=m.get("head_dim") or m["hidden_size"] // heads,
-        d_ff=m["intermediate_size"],
-        vocab=m["vocab_size"],
-        qkv_bias=bool(m.get("attention_bias", False)),
-        tie_embeddings=bool(m["tie_word_embeddings"]),
-        rope_theta=float(m["rope_theta"]),
-        norm_eps=float(m["rms_norm_eps"]),
-        dtype=s["dtype"],
-        vocab_multiple=s["embedding_rows_multiple"],
-        max_batch=s["max_batch"],
-        max_context=s["max_context"],
-        block_tokens=s["block_tokens"],
-    )
+def model_spec(conf: dict):
+    """The spec of a configuration file, made by the architecture module
+    its ``"architecture"`` key names."""
+    return arch.load(conf.get("architecture")).model_spec(conf)
 
 
 def load_json(path: Path) -> dict:
@@ -93,7 +49,8 @@ class Cell:
     name: str
     entry: dict              # the workload's entry
     config: dict             # the configuration file
-    spec: ModelSpec
+    arch: ModuleType         # the architecture module it names
+    spec: object             # made by that module from the file
     traffic: dict            # the traffic mix's file
     data: dict               # the cell's own file
     end_to_end: list[dict]   # metrics this cell reports with --trace 0
@@ -114,12 +71,13 @@ def resolve(cell_name: str, bench: dict | None = None,
     conf_entry = next(c for c in bench["configs"]
                       if c["name"] == entry["config"])
     config = load_json(ROOT / conf_entry["file"])
+    mod = arch.load(config.get("architecture"), base / "arch")
     e2e = [m for m in bench["end_to_end"] if _reports(m, cell_name)]
     e2e_names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"]
                  if m["moves"] in e2e_names and _reports(m, cell_name)]
-    return Cell(name=cell_name, entry=entry, config=config,
-                spec=model_spec(config),
+    return Cell(name=cell_name, entry=entry, config=config, arch=mod,
+                spec=mod.model_spec(config),
                 traffic=load_json(base / "traffic" / f"{entry['traffic']}.json"),
                 data=load_json(base / "cells" / f"{cell_name}.json"),
                 end_to_end=e2e, per_layer=per_layer)

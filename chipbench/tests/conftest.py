@@ -24,22 +24,29 @@ BIASED = {"hidden_size": 160, "intermediate_size": 256,
           "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 1200}
 
 
-def tiny_config(name: str, sizes: dict, dtype: str = "bfloat16",
-                max_batch: int = 4, max_context: int = 128) -> dict:
-    conf = copy.deepcopy(S.load_json(S.BENCH_DIR / "configs" / f"{name}.json"))
+def _shrink(conf: dict, sizes: dict, dtype: str = "bfloat16",
+            max_batch: int = 4, max_context: int = 128) -> dict:
+    conf = copy.deepcopy(conf)
     conf["model"].update(sizes)
     conf["serving"].update(dtype=dtype, max_batch=max_batch,
                            max_context=max_context)
     return conf
 
 
+def tiny_config(name: str, sizes: dict, **serving) -> dict:
+    return _shrink(S.load_json(S.BENCH_DIR / "configs" / f"{name}.json"),
+                   sizes, **serving)
+
+
 def tiny_cell(workload: str, sizes: dict | None = None,
-              traffic: str | None = None, **mix) -> S.Cell:
-    """A cell of BENCHMARK.json at a size the CPU runs in seconds;
-    ``traffic`` puts another mix of ``chipbench/traffic`` in its place."""
-    cell = S.resolve(workload)
-    cell.config = tiny_config(cell.config["name"], sizes or TIED)
-    cell.spec = S.model_spec(cell.config)
+              traffic: str | None = None, bench: dict | None = None,
+              base=S.BENCH_DIR, **mix) -> S.Cell:
+    """A cell of BENCHMARK.json (or of ``bench``, with its files under
+    ``base``) at a size the CPU runs in seconds; ``traffic`` puts another
+    mix of ``chipbench/traffic`` in its place."""
+    cell = S.resolve(workload, bench, base)
+    cell.config = _shrink(cell.config, sizes or TIED)
+    cell.spec = cell.arch.model_spec(cell.config)
     t = copy.deepcopy(S.load_json(S.BENCH_DIR / "traffic" / f"{traffic}.json")
                       if traffic else cell.traffic)
     if t["kind"] == "open_loop":

@@ -2,7 +2,8 @@
 import pytest
 
 from chipbench import flops, peaks, trace_reduce
-from chipbench.spec import ModelSpec
+from chipbench.arch import dense
+from chipbench.arch.dense import ModelSpec
 
 SPEC = ModelSpec(name="hand", n_layers=2, d_model=8, n_heads=4, n_kv_heads=2,
                  head_dim=2, d_ff=16, vocab=10, qkv_bias=False,
@@ -13,7 +14,7 @@ SPEC = ModelSpec(name="hand", n_layers=2, d_model=8, n_heads=4, n_kv_heads=2,
 
 def test_layer_weights_by_hand():
     # q: 8x8, k and v: 8x4 each, o: 8x8, MLP: 3 x 8x16
-    assert flops.layer_weights(SPEC) == 64 + 32 + 32 + 64 + 384
+    assert dense.layer_weights(SPEC) == 64 + 32 + 32 + 64 + 384
 
 
 def test_decode_step_by_hand():

@@ -1,20 +1,21 @@
-"""Seeded random weights of a dense decoder, made on the device.
+"""Seeded random weights of a model, made on the device.
 
-The tree has the layout the serving engine takes (``params["tok"]`` and a
-stack of ``params["layers"]`` with a leading layer axis).  Every leaf, and
-every layer of a stacked leaf, draws from a key of its own: the key of
-``--seed`` folded with the leaf's path and then with the layer index.  So
+The architecture's module (``chipbench/arch``) lists every leaf: its shape,
+dtype, scale and stack.  The tree has the layout the serving engine takes:
+``params["tok"]`` and one subtree per stack of layers, each leaf with a
+leading layer axis of its stack's depth.  Every leaf, and every layer of a
+stacked leaf, draws from a key of its own: the key of ``--seed`` folded
+with the leaf's path and then with the layer index within its stack.  So
 :func:`layer_params` remakes any one layer bit for bit without the others,
 which is how the reference gets its weights: from the seed, never from the
 program.
 
-Scales keep every sublayer's output near unit variance, so that each of
-the 2 x n_layers sublayers moves the residual stream; norm gains and
-biases are drawn as well (not zeros), so that they are exercised.
+Scales keep every sublayer's output near unit variance, so that each
+sublayer moves the residual stream; norm gains and biases are drawn as
+well (not zeros), so that they are exercised.
 """
 from __future__ import annotations
 
-import math
 import zlib
 from functools import partial
 
@@ -22,40 +23,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.spec import ModelSpec
+from chipbench import arch
 
 EMB_STD = 0.02        # token embedding (and the tied output head)
 NORM_STD = 0.1        # RMSNorm gains, stored as a delta from 1
 BIAS_STD = 0.5        # q/k/v projection biases
-
-
-def _leaves(spec: ModelSpec) -> dict:
-    """path -> (shape of one layer or of the whole leaf, dtype, std, stacked)"""
-    d, hd, f = spec.d_model, spec.head_dim, spec.d_ff
-    q, kv = spec.n_heads * hd, spec.n_kv_heads * hd
-    dt = jnp.dtype(spec.dtype)
-    f32 = jnp.float32
-    leaves = {
-        "tok/emb": ((spec.vocab_rows, d), dt, EMB_STD, False),
-        "tok/ln_f": ((d,), f32, NORM_STD, False),
-        "layers/ln1": ((d,), f32, NORM_STD, True),
-        "layers/attn/wq": ((d, q), dt, 1 / math.sqrt(d), True),
-        "layers/attn/wk": ((d, kv), dt, 1 / math.sqrt(d), True),
-        "layers/attn/wv": ((d, kv), dt, 1 / math.sqrt(d), True),
-        "layers/attn/wo": ((q, d), dt, 1 / math.sqrt(q), True),
-        "layers/ln2": ((d,), f32, NORM_STD, True),
-        "layers/mlp/w_up": ((d, f), dt, 1 / math.sqrt(d), True),
-        "layers/mlp/w_gate": ((d, f), dt, 1 / math.sqrt(d), True),
-        "layers/mlp/w_down": ((f, d), dt, 1 / math.sqrt(f), True),
-    }
-    if not spec.tie_embeddings:
-        leaves["tok/unemb"] = ((d, spec.vocab_rows), dt, 1 / math.sqrt(d),
-                               False)
-    if spec.qkv_bias:
-        leaves["layers/attn/bq"] = ((q,), dt, BIAS_STD, True)
-        leaves["layers/attn/bk"] = ((kv,), dt, BIAS_STD, True)
-        leaves["layers/attn/bv"] = ((kv,), dt, BIAS_STD, True)
-    return leaves
 
 
 def seed_words(seed: int) -> np.ndarray:
@@ -86,50 +58,61 @@ def _nest(flat: dict) -> dict:
     return tree
 
 
-def _make(words, spec: ModelSpec):
+def _make(words, spec):
     flat = {}
-    layers = jnp.arange(spec.n_layers)
-    for path, (shape, dt, std, stacked) in _leaves(spec).items():
+    mod = arch.of(spec)
+    depth = mod.stacks(spec)
+    for path, (shape, dt, std, stack) in mod.leaves(spec).items():
         k = _leaf_key(words, path)
-        if stacked:
+        if stack is not None:
             flat[path] = jax.vmap(
                 lambda i, k=k, shape=shape, dt=dt, std=std:
-                _draw(jax.random.fold_in(k, i), shape, dt, std))(layers)
+                _draw(jax.random.fold_in(k, i), shape, dt, std))(
+                    jnp.arange(depth[stack]))
         else:
             flat[path] = _draw(k, shape, dt, std)
     return _nest(flat)
 
 
-def make_params(spec: ModelSpec, seed: int):
+def make_params(spec, seed: int):
     """The whole parameter tree, on the default device, in one program."""
     return jax.jit(partial(_make, spec=spec))(seed_words(seed))
 
 
-def _layer(words, layer, spec: ModelSpec):
+def _only_stack(spec) -> str:
+    names = list(arch.of(spec).stacks(spec))
+    if len(names) != 1:
+        raise ValueError(f"{spec.name} has stacks {names}: name one")
+    return names[0]
+
+
+def _layer(words, layer, spec, stack: str):
     flat = {}
-    for path, (shape, dt, std, stacked) in _leaves(spec).items():
-        if stacked:
+    for path, (shape, dt, std, s) in arch.of(spec).leaves(spec).items():
+        if s == stack:
             k = jax.random.fold_in(_leaf_key(words, path), layer)
-            flat[path.removeprefix("layers/")] = _draw(k, shape, dt, std)
+            flat[path.removeprefix(f"{stack}/")] = _draw(k, shape, dt, std)
     return _nest(flat)
 
 
-def _token(words, spec: ModelSpec):
+def _token(words, spec):
     flat = {}
-    for path, (shape, dt, std, stacked) in _leaves(spec).items():
-        if not stacked:
+    for path, (shape, dt, std, stack) in arch.of(spec).leaves(spec).items():
+        if stack is None:
             flat[path.removeprefix("tok/")] = _draw(
                 _leaf_key(words, path), shape, dt, std)
     return _nest(flat)
 
 
-@partial(jax.jit, static_argnames=("spec",))
-def layer_params(words, layer, spec: ModelSpec):
-    """One layer's weights, equal to ``make_params(...)["layers"][layer]``."""
-    return _layer(words, layer, spec)
+@partial(jax.jit, static_argnames=("spec", "stack"))
+def layer_params(words, layer, spec, stack: str | None = None):
+    """One layer's weights, equal to ``make_params(...)[stack][layer]``;
+    ``stack`` may be left out where the architecture has one stack."""
+    return _layer(words, layer, spec,
+                  stack if stack is not None else _only_stack(spec))
 
 
 @partial(jax.jit, static_argnames=("spec",))
-def token_params(words, spec: ModelSpec):
+def token_params(words, spec):
     """Embedding, final norm and (untied) output head."""
     return _token(words, spec)
