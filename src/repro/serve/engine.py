@@ -97,11 +97,10 @@ class _ListSource:
 class _StreamSource:
     """Source over a ProxyStream consumer: requests arrive as stream items
     (optionally proxies, which the engine resolves with
-    :func:`_as_request`), end with the producer's close.  Polling is
-    non-blocking while rows are busy (a short channel wait) and blocking
-    when the engine is idle."""
-
-    _POLL_S = 0.002
+    :func:`_as_request`), end with the producer's close.  A non-blocking
+    poll (rows decoding, or a request already in hand) never waits: it
+    takes what the stream holds or returns ``None`` at once.  A blocking
+    poll (the engine idle) waits up to the configured ``timeout``."""
 
     def __init__(self, stream, *, timeout: float) -> None:
         self._stream = stream
@@ -114,7 +113,7 @@ class _StreamSource:
             return self._pending.popleft()
         if self._done:
             return _EXHAUSTED
-        self._stream.timeout = self._timeout if block else self._POLL_S
+        self._stream.timeout = self._timeout if block else 0
         try:
             item = next(self._stream)
         except StopIteration:

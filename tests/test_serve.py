@@ -15,7 +15,8 @@ from repro.core.connectors import FileConnector, SharedMemoryConnector
 from repro.core.proxy import extract, get_factory, is_proxy
 from repro.core.store import unregister_store
 from repro.models.serve_paths import KVBlockPool, KVPoolExhausted
-from repro.serve.engine import Request, ServeEngine, metrics_tap
+from repro.serve.engine import (_EXHAUSTED, Request, ServeEngine,
+                                _StreamSource, metrics_tap)
 from repro.train.checkpoints import ProxyCheckpointManager
 
 CFG = ARCHS["qwen2.5-14b"].reduced().replace(dtype="float32", n_layers=2)
@@ -182,7 +183,13 @@ def test_starved_pool_defers_admission_and_completes_all(engine):
 # ---------------------------------------------------------------------------
 # stream plane: requests in as proxies, completions out as evict proxies
 # ---------------------------------------------------------------------------
-def test_serve_stream_roundtrip(engine, shm_store):
+@pytest.mark.parametrize("staggered", [False, True],
+                         ids=["burst", "staggered"])
+def test_serve_stream_roundtrip(engine, shm_store, monkeypatch, staggered):
+    """Every request comes back exactly once with ``generate()``'s greedy
+    tokens.  ``staggered``: the feeder appends each request only after the
+    engine has run two decode steps since the last one, so each arrives
+    while earlier rows decode and is taken by a poll that does not wait."""
     rng = np.random.default_rng(29)
     reqs = [Request(prompt=_prompt(rng, 7), max_new_tokens=5,
                     req_id=f"s-{i}") for i in range(5)]
@@ -190,6 +197,18 @@ def test_serve_stream_roundtrip(engine, shm_store):
         reqs, engine.generate([Request(prompt=list(r.prompt),
                                        max_new_tokens=r.max_new_tokens)
                                for r in reqs])["outputs"])}
+    steps = [0]
+    stepped = threading.Condition()
+    if staggered:
+        decode = engine._decode
+
+        def counting_decode(*args):
+            with stepped:
+                steps[0] += 1
+                stepped.notify_all()
+            return decode(*args)
+
+        monkeypatch.setattr(engine, "_decode", counting_decode)
 
     def feed():
         prod = shm_store.stream_producer("req")
@@ -197,6 +216,11 @@ def test_serve_stream_roundtrip(engine, shm_store):
             prod.append(shm_store.proxy(
                 {"prompt": r.prompt, "max_new_tokens": r.max_new_tokens,
                  "req_id": r.req_id}, ttl=30.0))
+            if staggered:
+                with stepped:
+                    seen = steps[0]
+                    stepped.wait_for(lambda: steps[0] >= seen + 2,
+                                     timeout=30.0)
         prod.close()
 
     t = threading.Thread(target=feed)
@@ -206,6 +230,11 @@ def test_serve_stream_roundtrip(engine, shm_store):
                                 result_groups=("metrics",))
     t.join()
     assert stats["completed"] == len(reqs)
+    if staggered:
+        # one admission per arrival, and rows decoded side by side: fewer
+        # steps than the requests' 4 decode steps each run one after another
+        assert stats["spans"]["engine.admit"]["n"] == len(reqs)
+        assert stats["decode_steps"] < len(reqs) * 4
     got = {}
     for item in shm_store.stream_consumer("res", timeout=10.0):
         c = extract(item) if is_proxy(item) else item
@@ -218,6 +247,79 @@ def test_serve_stream_roundtrip(engine, shm_store):
     with metrics_tap(shm_store, "res", timeout=10.0) as tap:
         metas = {m["req_id"]: m["n_tokens"] for m in tap}
     assert metas == {rid: len(toks) for rid, toks in want.items()}
+
+
+class _RecordingStream:
+    """Stand-in for a stream consumer: records the ``timeout`` each
+    ``next()`` finds set, then returns the next scripted item or raises
+    it when it is an exception class."""
+
+    def __init__(self, script) -> None:
+        self._script = list(script)
+        self.timeout = None
+        self.timeouts: list = []
+
+    def __next__(self):
+        self.timeouts.append(self.timeout)
+        item = self._script.pop(0)
+        if isinstance(item, type) and issubclass(item, BaseException):
+            raise item
+        return item
+
+
+def test_stream_source_polls_without_waiting_while_not_blocking():
+    """A non-blocking poll sets the stream's timeout to 0, a blocking one
+    the source's own; an empty non-blocking poll returns ``None`` and
+    keeps the source open; the stream's end is ``_EXHAUSTED`` for good."""
+    stream = _RecordingStream([TimeoutError, "a", "b", TimeoutError,
+                               StopIteration])
+    src = _StreamSource(stream, timeout=7.5)
+    assert src.poll(block=False) is None
+    assert src.poll(block=True) == "a"
+    assert src.poll(block=False) == "b"
+    assert src.poll(block=False) is None
+    assert stream.timeouts == [0, 7.5, 0, 0]
+    src.push_back("c")
+    assert src.poll(block=False) == "c"      # in hand: no stream call
+    assert stream.timeouts == [0, 7.5, 0, 0]
+    assert src.poll(block=True) is _EXHAUSTED
+    assert stream.timeouts == [0, 7.5, 0, 0, 7.5]
+    assert src.poll(block=False) is _EXHAUSTED
+    assert len(stream.timeouts) == 5         # done: the stream is not called
+
+
+def test_stream_source_gives_up_after_a_blocking_timeout():
+    stream = _RecordingStream([TimeoutError])
+    src = _StreamSource(stream, timeout=3.0)
+    assert src.poll(block=True) is _EXHAUSTED
+    assert src.poll(block=False) is _EXHAUSTED
+    assert stream.timeouts == [3.0]
+
+
+def test_zero_timeout_take_never_waits_on_the_channel(shm_store):
+    """The cells' request path (a store's ``ProxyStream`` over the shared
+    memory connector's broker) with a timeout of 0: an empty take raises
+    ``TimeoutError`` without entering the channel's condition wait, and a
+    waiting item is taken."""
+    consumer = shm_store.stream_consumer("zt", timeout=0)
+    cond = shm_store.connector._channel_state()["cond"]
+
+    def no_wait(*args, **kwargs):
+        raise AssertionError("a zero-timeout take waited on the channel")
+
+    cond.wait = no_wait
+    try:
+        with pytest.raises(TimeoutError):
+            next(consumer)
+        prod = shm_store.stream_producer("zt")
+        prod.append(shm_store.proxy({"x": 1}, ttl=30.0))
+        got = next(consumer)
+        assert (extract(got) if is_proxy(got) else got) == {"x": 1}
+        with pytest.raises(TimeoutError):
+            next(consumer)
+    finally:
+        del cond.wait
+        consumer.close()
 
 
 def test_pool_byte_count_follows_puts_releases_and_reclaims(shm_store):
