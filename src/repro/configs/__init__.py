@@ -1,5 +1,5 @@
 """Assigned-architecture registry: ``get_arch(name)`` / ``--arch <id>``."""
-from repro.configs.base import ArchConfig, ShapeConfig, SHAPES, shape_applicable
+from repro.configs.base import ArchConfig
 
 from repro.configs.qwen2_5_14b import CONFIG as qwen2_5_14b
 from repro.configs.phi4_mini_3_8b import CONFIG as phi4_mini_3_8b
@@ -27,5 +27,4 @@ def get_arch(name: str) -> ArchConfig:
     return ARCHS[name]
 
 
-__all__ = ["ArchConfig", "ShapeConfig", "SHAPES", "shape_applicable",
-           "ARCHS", "get_arch"]
+__all__ = ["ArchConfig", "ARCHS", "get_arch"]

@@ -1,14 +1,14 @@
 """Architecture + run configuration.
 
 One ``ArchConfig`` instance per assigned architecture (see sibling modules).
-``reduced()`` returns a same-family miniature for CPU smoke tests.  The full
-configs are lowered abstractly (ShapeDtypeStruct) by the dry-run, and the
-ones that fit one TPU chip whole (phi4-mini-3.8b) are served at full width.
+``reduced()`` returns a same-family miniature for CPU smoke tests; the
+configs that fit one TPU chip whole (phi4-mini-3.8b) are served at full
+width.
 """
 from __future__ import annotations
 
 import dataclasses
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any
 
 
@@ -56,17 +56,12 @@ class ArchConfig:
     # --- VLM ---
     n_img_tokens: int = 0            # prefix positions carrying patch embeddings
 
-    # --- numerics / structure knobs (perf-relevant; see EXPERIMENTS §Perf) ---
+    # --- numerics / structure ---
     pad_vocab_to: int = 256   # embedding rows padded so 'model' axis divides
     dtype: str = "bfloat16"
-    scan_layers: bool = True
-    scan_group: int = 0              # >1: sqrt-remat over layer groups
-    remat: str = "full"              # full | none
     attn_chunk: int = 1024           # query-chunked reference attention
-    attn_unroll: bool = False        # python-loop the chunk scan (cost variant)
     loss_chunk: int = 512            # sequence-chunked softmax-xent
-    loss_unroll: bool = False
-    attention_impl: str = "chunked"  # chunked | full | pallas
+    attention_impl: str = "chunked"  # chunked | pallas
 
     @property
     def hd(self) -> int:
@@ -80,8 +75,7 @@ class ArchConfig:
 
         Indivisible vocabs (whisper 51865, mamba2 50280, internvl2 92553)
         otherwise force the logits/loss compute to replicate across the
-        'model' axis — measured as a >10x per-device FLOP blowup in the
-        dry-run (EXPERIMENTS.md §Perf).  Standard practice (MaxText et al.).
+        'model' axis.  Standard practice (MaxText et al.).
         """
         m = self.pad_vocab_to
         return ((self.vocab + m - 1) // m) * m if m else self.vocab
@@ -97,7 +91,7 @@ class ArchConfig:
     def replace(self, **kw) -> "ArchConfig":
         return dataclasses.replace(self, **kw)
 
-    # -- analytic parameter count (used for 6ND roofline terms) -------------
+    # -- analytic parameter count ---------------------------------------------
     def param_count(self) -> tuple[int, int]:
         """(total_params, active_params) — active differs for MoE."""
         D, FF, V, L = self.d_model, self.d_ff, self.vocab, self.n_layers
@@ -180,32 +174,3 @@ class ArchConfig:
             kw.update(sliding_window=64)
         return self.replace(**kw)
 
-
-# ---------------------------------------------------------------------------
-# Input-shape grid (assigned): every cell is (arch x one of these)
-# ---------------------------------------------------------------------------
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    kind: str          # train | prefill | decode
-    seq_len: int
-    global_batch: int
-
-
-SHAPES: dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", "train", 4_096, 256),
-    "prefill_32k": ShapeConfig("prefill_32k", "prefill", 32_768, 32),
-    "decode_32k": ShapeConfig("decode_32k", "decode", 32_768, 128),
-    "long_500k": ShapeConfig("long_500k", "decode", 524_288, 1),
-}
-
-
-def shape_applicable(arch: ArchConfig, shape: ShapeConfig) -> tuple[bool, str]:
-    """Whether the (arch, shape) cell runs (DESIGN.md §Arch-applicability)."""
-    if shape.name == "long_500k":
-        if arch.family in ("ssm", "hybrid"):
-            return True, "sub-quadratic (SSM state)"
-        if arch.sliding_window:
-            return True, "sub-quadratic (sliding-window KV)"
-        return False, "skipped: pure full attention at 512k ctx"
-    return True, ""

@@ -1,9 +1,9 @@
 """InternVL2-26B — InternViT frontend (STUB) + InternLM2-20B-style backbone.
 [arXiv:2404.16821; hf]
 
-The modality frontend is a stub per the assignment: ``input_specs()``
-provides precomputed patch embeddings occupying the first ``n_img_tokens``
-sequence positions.
+The modality frontend is a stub: the batch's ``vision_emb`` carries
+precomputed patch embeddings occupying the first ``n_img_tokens`` sequence
+positions.
 """
 from repro.configs.base import ArchConfig
 

@@ -23,8 +23,7 @@ their *contents* are only stable until the object is evicted — after
 which the owner may recycle the chunk.  Use refcounts/leases to pin
 objects consumers are still reading.
 
-Hardware adaptation note (DESIGN.md §2): no RDMA NIC exists in this
-container; POSIX shm is the intra-node analog of memory-to-memory
+Hardware adaptation note: no RDMA NIC is assumed; POSIX shm is the intra-node analog of memory-to-memory
 transfer.  Cross-node traffic falls to SocketConnector/KVServerConnector,
 as the paper's ZMQ fallback does.
 """

@@ -4,9 +4,9 @@ Microbatches stream through stages connected by ``jax.lax.ppermute`` inside a
 ``shard_map``; the schedule is the classic (n_micro + n_stages - 1)-tick
 pipeline with bubble fraction (S-1)/(M+S-1).
 
-Not part of the assigned 2-axis production mesh (DESIGN.md §5) — provided and
-tested (fake 8-device mesh, ``--selftest``) for deployments that add a
-'stage' axis.
+Not part of the 2-axis ("data", "model") mesh the logical rules target —
+provided and tested (fake 8-device mesh, ``--selftest``) for deployments that
+add a 'stage' axis.
 """
 from __future__ import annotations
 

@@ -1,8 +1,8 @@
-"""Per-arch logical-axis rules + parameter/batch/cache PartitionSpecs.
+"""Per-arch logical-axis rules + parameter/batch PartitionSpecs.
 
-The single rules dict drives everything: activations (via ``shard_as`` inside
-model code), parameters, optimizer state, batches and KV caches (via the
-PARAM_DIMS name->logical-dims table below).  ``resolve_spec`` silently drops
+The single rules dict drives everything: activations and KV caches (via
+``shard_as`` inside model code), parameters and optimizer state (via the
+leaf-name -> logical-dims tables below) and batches.  ``resolve_spec`` silently drops
 axes that don't divide, which implements the per-arch fallbacks:
 
 * qwen3-moe: experts (128) % model(16) == 0 -> EP on 'model'; moe_ff stays
@@ -17,24 +17,22 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed.sharding import resolve_spec
 
 # ---------------------------------------------------------------------------
 # logical rules
 # ---------------------------------------------------------------------------
-def make_rules(mesh: Mesh, *, fsdp: bool = True,
-               overrides: dict[str, Any] | None = None) -> dict[str, Any]:
+def make_rules(mesh: Mesh) -> dict[str, Any]:
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    fsdp_axes = batch_axes if fsdp else None
-    rules: dict[str, Any] = {
+    return {
         # activations
         "batch": batch_axes,
         "seq": None,
         "act_seq": None,     # residual-stream seq sharding (Megatron-SP) when set to 'model'
         "hd_tp": None,       # KV-cache head_dim sharding (alternative to cache_seq)
-        "attn_q": None,      # score-tensor q-position sharding (fixes GQA reshard; §Perf B3)
+        "attn_q": None,      # score-tensor q-position sharding (fixes GQA reshard)
         "embed": None,
         "heads": "model",
         "kv_heads": "model",
@@ -46,7 +44,7 @@ def make_rules(mesh: Mesh, *, fsdp: bool = True,
         "cache_seq": "model",
         # parameters
         "layers": None,
-        "p_embed": fsdp_axes,          # FSDP dim of weight matrices
+        "p_embed": batch_axes,         # FSDP dim of weight matrices
         "p_heads": "model",            # TP dim of weight matrices
         "p_ff": "model",
         "p_vocab": "model",
@@ -54,9 +52,6 @@ def make_rules(mesh: Mesh, *, fsdp: bool = True,
         "p_moe_ff": "model",
         "p_ssm": "model",
     }
-    if overrides:
-        rules.update(overrides)
-    return rules
 
 
 # ---------------------------------------------------------------------------
@@ -129,13 +124,8 @@ def tree_specs(mesh: Mesh, rules: dict, tree) -> Any:
     return jax.tree_util.tree_map_with_path(spec, tree)
 
 
-def tree_shardings(mesh: Mesh, rules: dict, tree) -> Any:
-    return jax.tree.map(lambda s: NamedSharding(mesh, s),
-                        tree_specs(mesh, rules, tree))
-
-
 # ---------------------------------------------------------------------------
-# batch / cache dims
+# batch dims
 # ---------------------------------------------------------------------------
 _BATCH_DIMS = {
     "tokens": ("batch", None),
@@ -144,29 +134,8 @@ _BATCH_DIMS = {
     "frames": ("batch", None, None),
 }
 
-_CACHE_DIMS = {
-    "k": ("layers", "batch", "cache_seq", "kv_heads", "hd_tp"),
-    "v": ("layers", "batch", "cache_seq", "kv_heads", "hd_tp"),
-    "ck": ("layers", "batch", None, "kv_heads", None),
-    "cv": ("layers", "batch", None, "kv_heads", None),
-    "conv": ("layers", "batch", None, "p_ssm"),
-    "state": ("layers", "batch", "ssm_heads", None, None),
-}
-
 
 def batch_specs_tree(mesh: Mesh, rules: dict, batch) -> Any:
     return {k: resolve_spec(mesh, v.shape, _BATCH_DIMS[k], rules)
             for k, v in batch.items()}
 
-
-def cache_specs_tree(mesh: Mesh, rules: dict, cache) -> Any:
-    def spec(path, leaf):
-        name = str(path[-1].key)
-        dims = _CACHE_DIMS[name]
-        if len(dims) != leaf.ndim:  # hybrid attn cache: sites leading dim
-            dims = (None,) + dims[1:] if leaf.ndim == len(dims) else dims
-            dims = dims[:leaf.ndim] if len(dims) > leaf.ndim else \
-                dims + (None,) * (leaf.ndim - len(dims))
-        return resolve_spec(mesh, leaf.shape, dims, rules)
-
-    return jax.tree_util.tree_map_with_path(spec, cache)

@@ -1,9 +1,10 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-Kept as functions (never module-level constants) so importing this module
-never touches JAX device state.  The dry-run process sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` *before* any jax
-import to obtain placeholder devices; smoke tests and benchmarks see 1.
+A function (never a module-level constant) so importing this module never
+touches JAX device state.  Multi-device tests set
+``XLA_FLAGS=--xla_force_host_platform_device_count=<n>`` in a subprocess
+*before* any jax import to obtain placeholder devices; the main test process
+and the benchmarks see 1.
 """
 from __future__ import annotations
 
@@ -20,12 +21,6 @@ def _axis_types_kwargs(n_axes: int) -> dict:
     if AxisType is None:
         return {}
     return {"axis_types": (AxisType.Auto,) * n_axes}
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
 
 
 def make_mesh(shape, axes) -> Mesh:

@@ -10,7 +10,6 @@ Conventions:
 from __future__ import annotations
 
 import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +49,7 @@ def rope(x, positions, theta: float):
 
 
 # ---------------------------------------------------------------------------
-# attention (GQA; chunked reference / full / pallas)
+# attention (GQA; chunked reference / pallas)
 # ---------------------------------------------------------------------------
 def _attn_chunk_body(q_chunk, k, v, *, q_start, causal, window, scale):
     """One query chunk vs the full K/V. q_chunk: (B, Cq, H, HD).
@@ -58,8 +57,7 @@ def _attn_chunk_body(q_chunk, k, v, *, q_start, causal, window, scale):
     The 'attn_q' rule (when set to 'model') pins the big score tensor to
     query-position sharding: with GQA kv_heads < mesh axis, head sharding
     can't cover the axis and GSPMD otherwise picks mismatched intermediate
-    shardings and reshards the O(S^2) scores per layer (§Perf B3 — measured
-    at 100-300 s of ICI time per step before this constraint).
+    shardings and reshards the O(S^2) scores every layer.
     """
     b, cq, h, hd = q_chunk.shape
     kv = k.shape[2]
@@ -94,35 +92,18 @@ def attention(q, k, v, cfg, *, causal=True, q_offset=0):
     """
     scale = 1.0 / math.sqrt(q.shape[-1])
     window = cfg.sliding_window
-    if cfg.attention_impl == "ablate":
-        # HLO-ablation stand-in (perf accounting): keeps shapes/graph around
-        # the attention site while removing its FLOPs/bytes, so lowering the
-        # same program with/without measures attention's exact contribution.
-        b, sq, h, hd = q.shape
-        # keep q/k/v live (cheap reductions) so XLA cannot dead-code the
-        # projections and over-attribute bytes to attention
-        stub = jnp.mean(k, axis=(1, 2)) + jnp.mean(v, axis=(1, 2))  # (B, HD)
-        return q * scale + stub[:, None, None, :].astype(q.dtype)
     if cfg.attention_impl == "pallas":
         from repro.kernels.ops import flash_attention as _fa
 
         return _fa(q, k, v, causal=causal, window=window, q_offset=q_offset)
     nq = q.shape[1]
     chunk = min(cfg.attn_chunk, nq)
-    if cfg.attention_impl == "full" or nq <= chunk or nq % chunk != 0:
+    if nq <= chunk or nq % chunk != 0:
         return _attn_chunk_body(q, k, v, q_start=q_offset, causal=causal,
                                 window=window, scale=scale)
 
     n_chunks = nq // chunk
     qs = q.reshape(q.shape[0], n_chunks, chunk, *q.shape[2:])
-
-    if cfg.attn_unroll:  # cost-variant: identical math, no while loop, so
-        # XLA cost analysis sees every chunk (see launch/dryrun.py)
-        outs = [_attn_chunk_body(qs[:, i], k, v,
-                                 q_start=q_offset + i * chunk,
-                                 causal=causal, window=window, scale=scale)
-                for i in range(n_chunks)]
-        return jnp.concatenate(outs, axis=1)
 
     @jax.checkpoint
     def body(_, qc_i):
@@ -295,10 +276,5 @@ def chunked_xent(p, x, labels, cfg, weights=None):
         return jnp.moveaxis(a.reshape(b, n, chunk, *a.shape[2:]), 1, 0)
 
     xs = (chunks(x), chunks(labels), chunks(weights))
-    if cfg.loss_unroll:  # cost-variant path (see attention above)
-        total = jnp.zeros((), jnp.float32)
-        for i in range(n):
-            total, _ = body(total, jax.tree.map(lambda a: a[i], xs))
-    else:
-        total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), xs)
+    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), xs)
     return total / jnp.maximum(jnp.sum(weights), 1.0)

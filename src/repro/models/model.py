@@ -1,12 +1,11 @@
-"""build_model(cfg) + abstract input specs for every (arch x shape) cell."""
+"""build_model(cfg) for every family, and abstract_params(cfg) (shapes only)."""
 from __future__ import annotations
 
 from typing import Any
 
 import jax
-import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig, ShapeConfig
+from repro.configs.base import ArchConfig
 from repro.models import serve_paths as S
 from repro.models import transformer as T
 
@@ -31,77 +30,6 @@ def build_model(cfg: ArchConfig) -> T.Model:
         decode_step=lambda params, cache, token, pos: decode(
             params, cache, token, pos, cfg),
     )
-
-
-# ---------------------------------------------------------------------------
-# abstract specs (ShapeDtypeStruct stand-ins; no allocation) — dry-run inputs
-# ---------------------------------------------------------------------------
-def _sds(shape, dtype):
-    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
-
-
-def batch_specs(cfg: ArchConfig, shape: ShapeConfig,
-                with_labels: bool = True) -> dict[str, Any]:
-    b, s = shape.global_batch, shape.seq_len
-    dt = jnp.dtype(cfg.dtype)
-    batch = {"tokens": _sds((b, s), jnp.int32)}
-    if with_labels:
-        batch["labels"] = _sds((b, s), jnp.int32)
-    if cfg.family == "vlm":
-        batch["vision_emb"] = _sds((b, cfg.n_img_tokens, cfg.d_model), dt)
-    if cfg.family == "audio":
-        batch["frames"] = _sds((b, cfg.enc_frames, cfg.d_model), dt)
-    return batch
-
-
-def cache_specs(cfg: ArchConfig, shape: ShapeConfig) -> Any:
-    b, s = shape.global_batch, shape.seq_len
-    lyr, kv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
-    dt = jnp.dtype(cfg.dtype)
-    w = min(s, cfg.sliding_window) if cfg.sliding_window else s
-
-    def ssm_cache(lead):
-        cd = cfg.d_inner + 2 * cfg.ssm_state
-        return {"conv": _sds((*lead, b, cfg.ssm_conv - 1, cd), dt),
-                "state": _sds((*lead, b, cfg.n_ssm_heads, cfg.ssm_head_dim,
-                               cfg.ssm_state), jnp.float32)}
-
-    if cfg.family in ("dense", "vlm", "moe"):
-        return {"k": _sds((lyr, b, w, kv, hd), dt),
-                "v": _sds((lyr, b, w, kv, hd), dt)}
-    if cfg.family == "audio":
-        f = cfg.enc_frames
-        return {"k": _sds((lyr, b, s, kv, hd), dt),
-                "v": _sds((lyr, b, s, kv, hd), dt),
-                "ck": _sds((lyr, b, f, kv, hd), dt),
-                "cv": _sds((lyr, b, f, kv, hd), dt)}
-    if cfg.family == "ssm":
-        return ssm_cache((lyr,))
-    if cfg.family == "hybrid":
-        n_sites = len(S._attn_sites(cfg))
-        return {"ssm": ssm_cache((lyr,)),
-                "attn": {"k": _sds((n_sites, b, s, kv, hd), dt),
-                         "v": _sds((n_sites, b, s, kv, hd), dt)}}
-    raise ValueError(cfg.family)
-
-
-def input_specs(cfg: ArchConfig, shape: ShapeConfig) -> dict[str, Any]:
-    """Abstract inputs for the step function this shape lowers.
-
-    train  -> {"batch": ...}                           (train_step)
-    prefill-> {"batch": ...} (no labels)               (prefill_step)
-    decode -> {"cache", "token", "pos"}                (serve_step)
-    """
-    b = shape.global_batch
-    if shape.kind == "train":
-        return {"batch": batch_specs(cfg, shape, with_labels=True)}
-    if shape.kind == "prefill":
-        return {"batch": batch_specs(cfg, shape, with_labels=False)}
-    if shape.kind == "decode":
-        return {"cache": cache_specs(cfg, shape),
-                "token": _sds((b, 1), jnp.int32),
-                "pos": _sds((), jnp.int32)}
-    raise ValueError(shape.kind)
 
 
 def abstract_params(cfg: ArchConfig) -> Any:

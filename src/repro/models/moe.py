@@ -97,11 +97,10 @@ def moe_block(p, x, cfg):
     Groups = batch rows (tokens never cross rows, so dispatch stays sharded
     over the batch axes).  Two dispatch implementations:
 
-    * ``einsum`` (GShard classic): dense one-hot dispatch/combine tensors —
-      MXU-friendly but costs O(S*k*E*C) extra FLOPs per layer, measured at
-      ~the cost of the experts themselves for mixtral (EXPERIMENTS §Perf);
-    * ``scatter`` (default): segment-sum into capacity slots + gather back,
-      O(S*k*D) — the beyond-paper optimization adopted after the hillclimb.
+    * ``einsum`` (GShard classic, the default): dense one-hot
+      dispatch/combine tensors — MXU-friendly but costs O(S*k*E*C) extra
+      FLOPs per layer, about the cost of the experts themselves for mixtral;
+    * ``scatter``: segment-sum into capacity slots + gather back, O(S*k*D).
     """
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.top_k

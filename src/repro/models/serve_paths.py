@@ -26,28 +26,8 @@ import numpy as np
 from repro.distributed.sharding import shard_as
 from repro.models import layers as L
 from repro.models.moe import moe_block, moe_decode
-from repro.models.ssm import (init_ssm_cache, ssm_decode_block,
-                              ssm_prefill_block)
-from repro.models.transformer import (_embed_inputs, _encode,
-                                      _shared_attn_block, _maybe_remat)
-
-
-def _layer_scan(body, carry, xs, cfg):
-    """lax.scan over the layer stack, or an unrolled loop when
-    cfg.scan_layers is False (the dry-run cost variant needs unrolled HLO
-    because XLA cost analysis counts while-loop bodies once)."""
-    if cfg.scan_layers:
-        return jax.lax.scan(body, carry, xs)
-    n = jax.tree.leaves(xs)[0].shape[0]
-    ys = []
-    for i in range(n):
-        carry, y = body(carry, jax.tree.map(lambda a: a[i], xs))
-        ys.append(y)
-    if ys and ys[0] is not None:
-        out = jax.tree.map(lambda *a: jnp.stack(a), *ys)
-    else:
-        out = None
-    return carry, out
+from repro.models.ssm import ssm_decode_block, ssm_prefill_block
+from repro.models.transformer import _embed_inputs, _encode
 
 
 def _cache_len(cfg, seq_len: int) -> int:
@@ -84,7 +64,7 @@ def decoder_prefill(params, batch, cfg):
         vc = _shard_cache(v[:, s - w:])
         return x, (kc, vc)
 
-    x, (ks, vs) = _layer_scan(_maybe_remat(body, cfg), x, params["layers"], cfg)
+    x, (ks, vs) = jax.lax.scan(jax.checkpoint(body), x, params["layers"])
     h = L.rms_norm(x, params["tok"]["ln_f"], cfg.norm_eps)
     logits = L.unembed(params["tok"], h[:, -1:], cfg)[:, 0]
     return logits.astype(jnp.float32), {"k": ks, "v": vs}
@@ -130,8 +110,8 @@ def decoder_decode_step(params, cache, token, pos, cfg):
             y = L.mlp_block(p["mlp"], h2, cfg)
         return (x + y, k_all, v_all), None
 
-    (x, ks, vs), _ = _layer_scan(body, (x, cache["k"], cache["v"]),
-                                 (params["layers"], jnp.arange(n_layers)), cfg)
+    (x, ks, vs), _ = jax.lax.scan(body, (x, cache["k"], cache["v"]),
+                                  (params["layers"], jnp.arange(n_layers)))
     h = L.rms_norm(x, params["tok"]["ln_f"], cfg.norm_eps)
     logits = L.unembed(params["tok"], h, cfg)[:, 0]
     return logits.astype(jnp.float32), {"k": ks, "v": vs}
@@ -163,8 +143,8 @@ def audio_prefill(params, batch, cfg):
         x = x + L.mlp_block(p["mlp"], L.rms_norm(x, p["ln2"], cfg.norm_eps), cfg)
         return x, (k, v, ck, cv)
 
-    x, (ks, vs, cks, cvs) = _layer_scan(_maybe_remat(body, cfg), x,
-                                        params["layers"], cfg)
+    x, (ks, vs, cks, cvs) = jax.lax.scan(jax.checkpoint(body), x,
+                                         params["layers"])
     h = L.rms_norm(x, params["tok"]["ln_f"], cfg.norm_eps)
     logits = L.unembed(params["tok"], h[:, -1:], cfg)[:, 0]
     return logits.astype(jnp.float32), {"k": ks, "v": vs, "ck": cks, "cv": cvs}
@@ -192,9 +172,9 @@ def audio_decode_step(params, cache, token, pos, cfg):
         x = x + L.mlp_block(p["mlp"], L.rms_norm(x, p["ln2"], cfg.norm_eps), cfg)
         return x, (kc, vc)
 
-    x, (ks, vs) = _layer_scan(
+    x, (ks, vs) = jax.lax.scan(
         body, x, (params["layers"], cache["k"], cache["v"], cache["ck"],
-                  cache["cv"]), cfg)
+                  cache["cv"]))
     h = L.rms_norm(x, params["tok"]["ln_f"], cfg.norm_eps)
     logits = L.unembed(params["tok"], h, cfg)[:, 0]
     return logits.astype(jnp.float32), {"k": ks, "v": vs, "ck": cache["ck"],
@@ -213,7 +193,7 @@ def ssm_prefill(params, batch, cfg):
                                  L.rms_norm(x, p["ln"], cfg.norm_eps), cfg)
         return shard_as(x + y, "batch", "act_seq", "embed"), c
 
-    x, cache = _layer_scan(_maybe_remat(body, cfg), x, params["layers"], cfg)
+    x, cache = jax.lax.scan(jax.checkpoint(body), x, params["layers"])
     h = L.rms_norm(x, params["tok"]["ln_f"], cfg.norm_eps)
     logits = L.unembed(params["tok"], h[:, -1:], cfg)[:, 0]
     return logits.astype(jnp.float32), cache
@@ -229,8 +209,8 @@ def ssm_decode_step(params, cache, token, pos, cfg):
                                 {"conv": conv, "state": state}, cfg)
         return x + y, (c["conv"], c["state"])
 
-    x, (convs, states) = _layer_scan(
-        body, x, (params["layers"], cache["conv"], cache["state"]), cfg)
+    x, (convs, states) = jax.lax.scan(
+        body, x, (params["layers"], cache["conv"], cache["state"]))
     h = L.rms_norm(x, params["tok"]["ln_f"], cfg.norm_eps)
     logits = L.unembed(params["tok"], h, cfg)[:, 0]
     return logits.astype(jnp.float32), {"conv": convs, "state": states}

@@ -7,9 +7,10 @@
 * ``prefill(params, batch) -> (last_logits, cache)``
 * ``decode_step(params, cache, token, pos) -> (logits, cache)``
 
-Layer stacks are ``lax.scan`` over stacked params (cfg.scan_layers) with
-per-layer remat — mandatory for the 126-layer/405B dry-run; the hybrid decode
-path is a Python loop (38 small layers, shared attention needs per-site KV).
+Layer stacks are one ``lax.scan`` over stacked params with per-layer remat,
+so the traced program and the saved activations do not grow with depth; the
+hybrid serving paths are a Python loop (38 small layers, shared attention
+needs per-site KV).
 """
 from __future__ import annotations
 
@@ -21,9 +22,8 @@ import jax.numpy as jnp
 
 from repro.distributed.sharding import shard_as
 from repro.models import layers as L
-from repro.models.moe import init_moe, moe_block, moe_decode
-from repro.models.ssm import (init_ssm, init_ssm_cache, ssm_block,
-                              ssm_decode_block)
+from repro.models.moe import init_moe, moe_block
+from repro.models.ssm import init_ssm, ssm_block
 
 
 @dataclass
@@ -35,45 +35,17 @@ class Model:
     decode_step: Callable
 
 
-def _maybe_remat(fn, cfg):
-    return jax.checkpoint(fn) if cfg.remat == "full" else fn
-
-
 def _stacked(init_one, key, n):
     """vmap an init over layer indices -> stacked (L, ...) params."""
     return jax.vmap(init_one)(jax.random.split(key, n))
 
 
-def _scan_layers(body, x, stacked_params, cfg, extra=None):
-    """Scan ``body(x, layer_params, extra) -> x`` over the layer stack.
-
-    cfg.scan_group > 0 enables sqrt-remat: an outer scan over G groups whose
-    body (an inner scan over L/G layers) is itself rematerialized — the
-    bwd-saved residual stack shrinks from L x |x| to (G + L/G) x |x|
-    (classic sqrt(L) checkpointing; the 405B memory lever in §Perf).
-    """
-    fn = _maybe_remat(lambda carry, p: (body(carry, p, extra), None), cfg)
-    if cfg.scan_layers and cfg.scan_group > 1:
-        n = jax.tree.leaves(stacked_params)[0].shape[0]
-        g = cfg.scan_group
-        assert n % g == 0, (n, g)
-        grouped = jax.tree.map(
-            lambda a: a.reshape(g, n // g, *a.shape[1:]), stacked_params)
-
-        @jax.checkpoint
-        def group_body(carry, group_params):
-            carry, _ = jax.lax.scan(fn, carry, group_params)
-            return carry, None
-
-        x, _ = jax.lax.scan(group_body, x, grouped)
-        return x
-    if cfg.scan_layers:
-        x, _ = jax.lax.scan(fn, x, stacked_params)
-        return x
-    n = jax.tree.leaves(stacked_params)[0].shape[0]
-    for i in range(n):
-        x, _ = fn(x, jax.tree.map(lambda a: a[i], stacked_params))
-    return x
+def _scan_stack(body, carry, stacked_params):
+    """Scan ``body(carry, layer_params) -> carry`` over the layer stack,
+    each layer rematerialized in the backward pass."""
+    carry, _ = jax.lax.scan(jax.checkpoint(lambda c, p: (body(c, p), None)),
+                            carry, stacked_params)
+    return carry
 
 
 # ---------------------------------------------------------------------------
@@ -130,28 +102,17 @@ def _embed_inputs(params, batch, cfg):
 def _decoder_hidden(params, x, cfg, positions):
     """Dense/vlm/moe decoder stack -> (hidden, aux)."""
     if cfg.family == "moe":
-        def body(carry, p, _):
+        def body(carry, p):
             return _moe_body(carry, p, cfg, positions)
         aux0 = {"lb_loss": jnp.zeros((), jnp.float32),
                 "z_loss": jnp.zeros((), jnp.float32)}
-        x, aux = _scan_layers_carry(body, (x, aux0), params["layers"], cfg)
+        x, aux = _scan_stack(body, (x, aux0), params["layers"])
     else:
-        def body(x, p, _):
+        def body(x, p):
             return _dense_body(x, p, cfg, positions)
-        x = _scan_layers(body, x, params["layers"], cfg)
+        x = _scan_stack(body, x, params["layers"])
         aux = {}
     return L.rms_norm(x, params["tok"]["ln_f"], cfg.norm_eps), aux
-
-
-def _scan_layers_carry(body, carry, stacked_params, cfg):
-    fn = _maybe_remat(lambda c, p: (body(c, p, None), None), cfg)
-    if cfg.scan_layers:
-        carry, _ = jax.lax.scan(fn, carry, stacked_params)
-        return carry
-    n = jax.tree.leaves(stacked_params)[0].shape[0]
-    for i in range(n):
-        carry, _ = fn(carry, jax.tree.map(lambda a: a[i], stacked_params))
-    return carry
 
 
 # ---------------------------------------------------------------------------
@@ -175,7 +136,7 @@ def _hybrid_hidden(params, x, cfg, positions):
     shared = params["shared"]
     flags = (jnp.arange(cfg.n_layers) % cfg.attn_every) == 0
 
-    def body(x, p_flag, _):
+    def body(x, p_flag):
         p, flag = p_flag
         x = jax.lax.cond(
             flag,
@@ -183,7 +144,7 @@ def _hybrid_hidden(params, x, cfg, positions):
             lambda x: x, x)
         return _ssm_body(x, p, cfg)
 
-    x = _scan_layers(body, x, (params["layers"], flags), cfg)
+    x = _scan_stack(body, x, (params["layers"], flags))
     return L.rms_norm(x, params["tok"]["ln_f"], cfg.norm_eps), {}
 
 
@@ -210,10 +171,10 @@ def _encode(params, frames, cfg):
     x = frames.astype(L.dtype_of(cfg)) + params["enc_pos"].astype(L.dtype_of(cfg))
     pos = jnp.arange(frames.shape[1])
 
-    def body(x, p, _):
+    def body(x, p):
         return _enc_body(x, p, cfg, pos)
 
-    x = _scan_layers(body, x, params["enc"], cfg)
+    x = _scan_stack(body, x, params["enc"])
     return L.rms_norm(x, params["enc_ln_f"], cfg.norm_eps)
 
 
@@ -270,11 +231,11 @@ def model_loss(params, batch, cfg):
     if cfg.family == "audio":
         memory = _encode(params, batch["frames"], cfg)
 
-        def body(x, p, _):
+        def body(x, p):
             return _dec_body(x, p, cfg, positions, memory)
 
         x = _embed_inputs(params, batch, cfg)
-        x = _scan_layers(body, x, params["layers"], cfg)
+        x = _scan_stack(body, x, params["layers"])
         h = L.rms_norm(x, params["tok"]["ln_f"], cfg.norm_eps)
         aux = {}
     elif cfg.family == "hybrid":
@@ -283,10 +244,10 @@ def model_loss(params, batch, cfg):
     elif cfg.family == "ssm":
         x = _embed_inputs(params, batch, cfg)
 
-        def body(x, p, _):
+        def body(x, p):
             return _ssm_body(x, p, cfg)
 
-        x = _scan_layers(body, x, params["layers"], cfg)
+        x = _scan_stack(body, x, params["layers"])
         h = L.rms_norm(x, params["tok"]["ln_f"], cfg.norm_eps)
         aux = {}
     else:

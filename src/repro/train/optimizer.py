@@ -1,14 +1,12 @@
 """AdamW + schedules, implemented directly in JAX (no optax dependency).
 
-``moment_dtype`` is the 405B-on-one-pod knob (EXPERIMENTS.md §Dry-run):
-bf16 first/second moments shrink optimizer state 2x; fp32 remains the
-default.  Global-norm clipping and decoupled weight decay included.
+First/second moments are kept in fp32.  Global-norm clipping and decoupled
+weight decay included.
 """
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +23,6 @@ class OptConfig:
     eps: float = 1e-8
     weight_decay: float = 0.1
     clip_norm: float = 1.0
-    moment_dtype: str = "float32"   # float32 | bfloat16
 
 
 def lr_at(step, cfg: OptConfig):
@@ -38,8 +35,7 @@ def lr_at(step, cfg: OptConfig):
 
 
 def init_opt_state(params, cfg: OptConfig):
-    mdt = jnp.dtype(cfg.moment_dtype)
-    zeros = lambda p: jnp.zeros(p.shape, mdt)
+    zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
     return {"mu": jax.tree.map(zeros, params),
             "nu": jax.tree.map(zeros, params),
             "step": jnp.zeros((), jnp.int32)}
@@ -64,20 +60,19 @@ def adamw_update(grads, opt_state, params, cfg: OptConfig):
     scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-9)) \
         if cfg.clip_norm else 1.0
     lr = lr_at(step, cfg)
-    mdt = jnp.dtype(cfg.moment_dtype)
 
     b1c = 1 - cfg.b1 ** step.astype(jnp.float32)
     b2c = 1 - cfg.b2 ** step.astype(jnp.float32)
 
     def upd(path, p, g, mu, nu):
         g = g.astype(jnp.float32) * scale
-        mu32 = cfg.b1 * mu.astype(jnp.float32) + (1 - cfg.b1) * g
-        nu32 = cfg.b2 * nu.astype(jnp.float32) + (1 - cfg.b2) * g * g
-        update = (mu32 / b1c) / (jnp.sqrt(nu32 / b2c) + cfg.eps)
+        mu = cfg.b1 * mu + (1 - cfg.b1) * g
+        nu = cfg.b2 * nu + (1 - cfg.b2) * g * g
+        update = (mu / b1c) / (jnp.sqrt(nu / b2c) + cfg.eps)
         if cfg.weight_decay and _decayable(path):
             update = update + cfg.weight_decay * p.astype(jnp.float32)
         new_p = p.astype(jnp.float32) - lr * update
-        return new_p.astype(p.dtype), mu32.astype(mdt), nu32.astype(mdt)
+        return new_p.astype(p.dtype), mu, nu
 
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
     treedef = jax.tree.structure(params)

@@ -1,10 +1,9 @@
-"""Train-state + step factories (the functions the dry-run lowers)."""
+"""Train-state + step factories (what ``Trainer`` jits)."""
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Callable
 
 import jax
-import jax.numpy as jnp
 
 from repro.models.model import build_model
 from repro.train.optimizer import OptConfig, adamw_update, init_opt_state
@@ -16,85 +15,15 @@ def init_train_state(key, cfg, opt_cfg: OptConfig):
     return {"params": params, "opt": init_opt_state(params, opt_cfg)}
 
 
-def abstract_train_state(cfg, opt_cfg: OptConfig):
-    return jax.eval_shape(
-        lambda: init_train_state(jax.random.key(0), cfg, opt_cfg))
-
-
-def make_train_step(cfg, opt_cfg: OptConfig, *,
-                    n_microbatches: int = 1) -> Callable:
-    """Standard step, or gradient-accumulation over ``n_microbatches``
-    (scan over batch slices; peak activation memory scales ~1/n at the cost
-    of n sequential passes — a §Perf memory lever for the 405B cell)."""
+def make_train_step(cfg, opt_cfg: OptConfig) -> Callable:
     model = build_model(cfg)
     grad_fn = jax.value_and_grad(model.loss, has_aux=True)
 
     def train_step(state, batch):
-        if n_microbatches == 1:
-            (loss, metrics), grads = grad_fn(state["params"], batch)
-        else:
-            def split(a):
-                b = a.shape[0]
-                assert b % n_microbatches == 0, (b, n_microbatches)
-                mb = b // n_microbatches
-                return jnp.moveaxis(
-                    a.reshape(n_microbatches, mb, *a.shape[1:]), 0, 0)
-
-            micro = jax.tree.map(split, batch)
-            zeros = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), state["params"])
-
-            def acc_body(carry, mb):
-                grads_acc, loss_acc, metrics_acc = carry
-                (loss, metrics), grads = grad_fn(state["params"], mb)
-                grads_acc = jax.tree.map(
-                    lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
-                metrics_acc = {k: metrics_acc.get(k, 0.0) + v
-                               for k, v in metrics.items()}
-                return (grads_acc, loss_acc + loss, metrics_acc), None
-
-            metrics0 = {k: jnp.zeros((), jnp.float32)
-                        for k in (["nll", "lb_loss", "z_loss"]
-                                  if cfg.family == "moe" else ["nll"])}
-            (grads, loss, metrics), _ = jax.lax.scan(
-                acc_body, (zeros, jnp.zeros((), jnp.float32), metrics0),
-                micro)
-            inv = 1.0 / n_microbatches
-            grads = jax.tree.map(lambda g: g * inv, grads)
-            loss = loss * inv
-            metrics = {k: v * inv for k, v in metrics.items()}
+        (loss, metrics), grads = grad_fn(state["params"], batch)
         new_params, new_opt, stats = adamw_update(
             grads, state["opt"], state["params"], opt_cfg)
         metrics = dict(metrics, loss=loss, **stats)
         return {"params": new_params, "opt": new_opt}, metrics
 
     return train_step
-
-
-def make_eval_step(cfg) -> Callable:
-    model = build_model(cfg)
-
-    def eval_step(params, batch):
-        loss, metrics = model.loss(params, batch)
-        return dict(metrics, loss=loss)
-
-    return eval_step
-
-
-def make_prefill_step(cfg) -> Callable:
-    model = build_model(cfg)
-
-    def prefill_step(params, batch):
-        return model.prefill(params, batch)
-
-    return prefill_step
-
-
-def make_serve_step(cfg) -> Callable:
-    model = build_model(cfg)
-
-    def serve_step(params, cache, token, pos):
-        logits, cache = model.decode_step(params, cache, token, pos)
-        return logits, cache
-
-    return serve_step

@@ -5,7 +5,7 @@ import sys
 # the TPU (which belongs to one process at a time)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # NOTE: no XLA_FLAGS here by design — smoke tests and benches must see ONE
-# device; only launch/dryrun.py (a separate process) forces 512.
+# device; multi-device tests force fake devices in a subprocess.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # repo root, so the benchmark smoke tests can import the benchmarks package
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))

@@ -5,7 +5,8 @@ import numpy as np
 import pytest
 
 from hlo_ops import cache_aliases, ops_of_shape
-from repro.configs import ARCHS, SHAPES, get_arch, shape_applicable
+from repro.configs import ARCHS, get_arch
+from repro.models import layers as L
 from repro.models.model import build_model
 from repro.serve.engine import ServeEngine
 
@@ -122,40 +123,56 @@ def test_ssd_chunk_invariance():
     assert abs(float(l1) - float(l2)) < 1e-5
 
 
-@pytest.mark.parametrize("step,arch", [
-    ("loss", "qwen2.5-14b"), ("loss", "mamba2-2.7b"),
-    ("decode", "qwen2.5-14b"), ("decode", "qwen3-moe-30b-a3b")])
-def test_scan_vs_unrolled_equivalence(step, arch):
-    cfg = ARCHS[arch].reduced().replace(dtype="float32")
-    m_scan = build_model(cfg)
-    m_loop = build_model(cfg.replace(scan_layers=False))
-    params = m_scan.init(jax.random.key(0))
-    if step == "loss":
-        batch = tiny_batch(cfg)
-        l1, _ = m_scan.loss(params, batch)
-        l2, _ = m_loop.loss(params, batch)
-        assert abs(float(l1) - float(l2)) < 1e-5
-        return
-    # per-row positions into a cache carried through the layers
-    B, S = 3, 24
-    shape = (cfg.n_layers, B, S, cfg.n_kv_heads, cfg.hd)
+@pytest.mark.parametrize("causal,window", [(True, 0), (True, 16),
+                                           (False, 0)],
+                         ids=["causal", "window16", "noncausal"])
+def test_chunked_attention_matches_one_chunk(causal, window):
+    """The query-chunk scan over four chunks gives what one chunk over all
+    the queries gives: each chunk's positions, mask and window line up."""
+    cfg = ARCHS["qwen2.5-14b"].reduced().replace(
+        dtype="float32", sliding_window=window)
+    B, S = 2, 256
+    ks = jax.random.split(jax.random.key(0), 3)
+    q = jax.random.normal(ks[0], (B, S, cfg.n_heads, cfg.hd))
+    k = jax.random.normal(ks[1], (B, S, cfg.n_kv_heads, cfg.hd))
+    v = jax.random.normal(ks[2], (B, S, cfg.n_kv_heads, cfg.hd))
+    chunked = L.attention(q, k, v, cfg.replace(attn_chunk=64), causal=causal)
+    whole = L.attention(q, k, v, cfg.replace(attn_chunk=S), causal=causal)
+    np.testing.assert_allclose(np.asarray(chunked), np.asarray(whole),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "qwen2.5-14b"],
+                         ids=["tied", "untied"])
+def test_chunked_xent_matches_plain_cross_entropy(arch, masked):
+    """The sequence-chunked loss equals logsumexp minus the gold logit on
+    the full float32 logits, averaged over the weighted positions."""
+    cfg = ARCHS[arch].reduced().replace(dtype="float32", loss_chunk=64)
+    B, S = 2, 256
+    tok = L.init_embeddings(jax.random.key(0), cfg)
     ks = jax.random.split(jax.random.key(1), 3)
-    cache = {"k": jax.random.normal(ks[0], shape),
-             "v": jax.random.normal(ks[1], shape)}
-    tok = jax.random.randint(ks[2], (B, 1), 0, cfg.vocab)
-    pos = jnp.asarray([2, 9, S - 1], jnp.int32)
-    got = m_scan.decode_step(params, cache, tok, pos)
-    want = m_loop.decode_step(params, cache, tok, pos)
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-5)
+    x = jax.random.normal(ks[0], (B, S, cfg.d_model))
+    labels = jax.random.randint(ks[1], (B, S), 0, cfg.vocab)
+    weights = (jax.random.uniform(ks[2], (B, S)) < 0.5).astype(jnp.float32) \
+        if masked else None
+    got = L.chunked_xent(tok, x, labels, cfg, weights=weights)
+
+    w = tok["emb"].T if cfg.tie_embeddings else tok["unemb"]
+    logits = (x @ w).astype(jnp.float32)
+    gold = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
+    nll = jax.nn.logsumexp(logits, -1) - gold
+    wts = jnp.ones((B, S)) if weights is None else weights
+    want = jnp.sum(nll * wts) / jnp.maximum(jnp.sum(wts), 1.0)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
 
 
-def test_continuous_decode_matches_full_forward():
+@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "qwen2.5-14b"])
+def test_continuous_decode_matches_full_forward(arch):
     """Rows at different lengths decode several steps into one donated
     cache, as the continuous engine runs them: every step's logits equal
     the full forward over that row's prefix."""
-    cfg = ARCHS["qwen2.5-14b"].reduced().replace(dtype="float32")
+    cfg = ARCHS[arch].reduced().replace(dtype="float32")
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
     lens, steps, S = (5, 11, 18), 4, 32
@@ -219,9 +236,3 @@ def test_param_counts_match_published():
         tot, _ = get_arch(name).param_count()
         assert abs(tot / 1e9 - billions) / billions < 0.06, name
 
-
-def test_shape_applicability_matrix():
-    runnable = sum(
-        shape_applicable(a, s)[0]
-        for a in ARCHS.values() for s in SHAPES.values())
-    assert runnable == 33  # 40 cells - 7 long_500k full-attention skips
